@@ -52,7 +52,7 @@ _COMMON = frozenset({
     "broadcast_in_dim", "reshape", "concatenate", "slice", "squeeze",
     "transpose", "iota", "convert_element_type", "shift_left",
     "shift_right_logical", "shift_right_arithmetic", "clamp",
-    "gather", "scatter", "pjit", "scan", "while", "cond",
+    "gather", "scatter", "jit", "stop_gradient", "scan", "while", "cond",
     "reduce_or", "reduce_and", "pad", "copy", "dynamic_slice",
     "dynamic_update_slice",
 })
@@ -65,7 +65,11 @@ FAMILY_PRIMS: Dict[str, frozenset] = {
     "fold": _COMMON | {"scatter-add"},
     "synth": _COMMON | {"argmax", "cumsum", "device_put", "div",
                         "rem", "reduce_max", "sign"},
-    "pallas": _COMMON | {"pallas_call", "program_id", "get", "swap"},
+    # The Pallas kernel computes on int32 lane vectors: lane rolls
+    # stand in for the scan's mask reshapes, a max-reduce answers
+    # "any bit set", and the frontier bitcasts back to uint32.
+    "pallas": _COMMON | {"pallas_call", "program_id", "get", "swap",
+                         "roll", "reduce_max", "bitcast_convert_type"},
     # The decrease-and-conquer peel loop is segment folds + gathers on
     # the VPU: scatter-min/max by cluster id, argmin for the two-minima
     # outside bound, reduce_min for the second minimum. A dot_general
@@ -111,8 +115,8 @@ def _finding(rule: str, family: str, msg: str,
 def walk_jaxpr(jaxpr, prims: set, dtypes: set) -> None:
     """Collect primitive names and aval dtypes over a jaxpr and every
     sub-jaxpr reachable through eqn params (scan/while/cond bodies,
-    pjit calls, pallas_call kernels)."""
-    from jax import core as jc
+    jit calls, pallas_call kernels)."""
+    from jax.extend import core as jc
 
     for v in (list(jaxpr.invars) + list(jaxpr.outvars)
               + list(jaxpr.constvars)):

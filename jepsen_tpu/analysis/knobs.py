@@ -81,8 +81,6 @@ _k("JT_PREWARM_WAIT_S", "600", "float", "ops/schedule.py",
 _k("JT_COMPILE_CACHE", "1", "flag", "ops/schedule.py",
    "Persistent XLA compile cache + AOT shipping (0 disables both — "
    "the hermetic-tests contract).")
-_k("JT_COMPILE_CACHE_DIR", None, "path", "ops/schedule.py",
-   "Compile-cache directory (default ~/.cache/jepsen_tpu/xla).")
 _k("JT_AOT_DIR", None, "path", "ops/schedule.py",
    "AOT-serialized kernel directory; unset disables shipping.")
 _k("JT_DISPATCH_OVERHEAD_US", None, "float", "ops/schedule.py",
@@ -267,12 +265,6 @@ _k("JT_BENCH_GRAPH_B", "2000", "int", "bench.py",
    "Graphs for the graph-checker section.")
 _k("JT_BENCH_ISO_B", "512", "int", "bench.py",
    "Transactional histories for the isolation-certifier section.")
-_k("JT_BENCH_MXU_TMACS", "98.5", "float", "bench.py",
-   "Assumed peak MXU TMAC/s for mxu_util.")
-_k("JT_BENCH_VPU_GOPS", "6800", "float", "bench.py",
-   "Assumed peak VPU Gop/s for vpu_util.")
-_k("JT_BENCH_HBM_PEAK_GBPS", "819", "float", "bench.py",
-   "Assumed peak HBM GB/s for the roofline.")
 _k("JT_BENCH_WAL_OPS", "20000", "int", "bench.py",
    "Ops for the run-durability WAL section.")
 _k("JT_BENCH_LONG_B", "1000", "int", "bench.py",

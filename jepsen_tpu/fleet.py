@@ -1007,7 +1007,9 @@ def fleet_worker(campaign_dir, worker_id: str, *,
         telemetry.set_correlation(prev_corr)
         if swriter is not None:
             swriter.close(final_frame=True)
+    from .provision import device_identity
     summary = {**stats, "router": router.summary(),
+               "device": device_identity(),
                "telemetry": telemetry.counters_delta(
                    tel_base, telemetry.snapshot())}
     atomic_write_json(cdir / f"worker-{worker_id}.json", summary)
@@ -1232,6 +1234,7 @@ def merge_campaign(campaign_dir) -> dict:
         workers[wid] = {k: wsum.get(k, 0) for k in
                         ("chunks", "units", "rehydrated", "takeovers",
                          "abandoned", "errors")}
+        workers[wid]["device"] = wsum.get("device")
         r = wsum.get("router") or {}
         for k, v in (r.get("chosen") or {}).items():
             chosen[k] = chosen.get(k, 0) + v

@@ -479,7 +479,7 @@ class EncodedBatch:
       ev_opidx — int32 [B, N] (host-side only, never shipped to device)
       target   — int32 [B, K + 1, V]; final row = all-invalid sentinel
     Event arrays are deliberately narrow: host→device transfer of the
-    batch is a real cost (PCIe at best, a network tunnel at worst), and
+    batch is a real cost (a PCIe round trip per call), and
     the kernel widens on device. ``shared_target`` marks every row
     sharing one transition table (one [K+1, V] transfer instead of B).
     ``indices`` maps batch rows back to positions in the caller's history

@@ -25,7 +25,7 @@ Fault kinds model the real failure classes seen in production:
                   discarded; the retry wins);
   * ``wedge``   — like timeout but far past the deadline: the dispatch
                   never comes back in useful time (the wedged-RPC /
-                  dead-tunnel threat model, see DaemonFuture);
+                  hung-runtime threat model, see DaemonFuture);
   * ``corrupt`` — the decoded verdict arrays are garbage; caught by
                   ``validate_decoded`` and treated as a retryable
                   fault (persistent corruption bisects down to the

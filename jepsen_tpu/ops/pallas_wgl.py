@@ -7,14 +7,16 @@ dispatch/latency-bound (the r05 roofline: ``hbm_util`` 0.0018). This
 module hand-schedules the same search as ONE Pallas program per
 history batch:
 
-  * the packed ``[words(V), 2^W]`` uint32 frontier stays RESIDENT in
-    VMEM across *all* events of a history (grid = (batch, event
+  * the packed ``[words(V), 2^W]`` frontier stays RESIDENT in VMEM
+    across *all* events of a history (grid = (row blocks of 8, event
     blocks); the frontier output block re-maps to the same VMEM tile
-    for every event block of a row, so it never round-trips to HBM
-    until the history is decided);
-  * events stream from HBM in ``JT_PALLAS_EVENT_BLOCK``-sized blocks —
-    Pallas' pipeline fetches block k+1 while block k computes, the
-    double-buffering the scan kernel pays dispatch overhead for;
+    for every event block of a row block, so it never round-trips to
+    HBM until the history is decided);
+  * events stream from HBM into SMEM in ``JT_PALLAS_EVENT_BLOCK``-sized
+    blocks — Pallas' pipeline fetches block k+1 while block k
+    computes, the double-buffering the scan kernel pays dispatch
+    overhead for; the transition rows sit in SMEM beside them, read
+    as scalars;
   * closure iterations run to fixpoint IN-KERNEL (a while loop over
     VPU work on the resident frontier) instead of per-iteration XLA
     round trips, and a decided row skips the remaining event blocks
@@ -41,7 +43,6 @@ parity gate (tests/test_pallas.py) on the CPU tier-1 box.
 """
 from __future__ import annotations
 
-import functools
 import os
 import time
 from typing import Dict, Optional, Tuple
@@ -94,22 +95,11 @@ def pallas_mode() -> str:
     m = os.environ.get("JT_PALLAS_MODE")
     if m in ("compiled", "interpret", "off"):
         return m
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        return "off"
-    return "compiled" if backend == "tpu" else "interpret"
+    return "compiled" if jax.default_backend() == "tpu" else "interpret"
 
 
 def pallas_available() -> bool:
-    if pallas_mode() == "off":
-        return False
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-    except Exception:
-        return False
-    return True
+    return pallas_mode() != "off"
 
 
 def vmem_budget_bytes() -> int:
@@ -128,48 +118,57 @@ def vmem_budget_bytes() -> int:
 #: measurement — the model must reject before launch, so it errs big).
 VMEM_SCRATCH_FACTOR = 3
 
+#: SMEM the kernel's scalar tables may take: one TPU v5e core's 1 MiB
+#: (jax's pallas tpu_info table), less a quarter for Mosaic's own use.
+SMEM_BUDGET_BYTES = 768 << 10
+
 
 def vmem_plan(V: int, W: int, *, K1: int = 256,
               eb: Optional[int] = None,
-              budget: Optional[int] = None) -> Dict[str, object]:
+              budget: Optional[int] = None,
+              shared: bool = True) -> Dict[str, object]:
     """Static VMEM/SMEM footprint of one Pallas program instance —
     the reject-before-launch model (analysis.jaxpr_lint rule
     JTL-D-VMEM prices every supported (V, W) against it, and
     ``pallas_supports`` consults it so an OOM config is never even
-    routed). Components: the VMEM-resident frontier output tile
-    [words(V), 2^W] uint32 with its closure scratch, the packed
-    transition rows [words(V), K1, V], and the double-buffered SMEM
-    event block. ``K1`` bounds the kind vocabulary (the rows table);
-    callers with a real vocabulary pass theirs."""
-    NW, M = n_state_words(V), 1 << int(W)
+    routed). VMEM holds the double-buffered [ROW_BLOCK, words(V), L]
+    frontier block with its closure scratch; SMEM holds the
+    double-buffered event blocks (W slot columns) and the packed
+    transition rows [words(V) * K1, V] (per row block when targets
+    are not shared). ``K1`` bounds the kind vocabulary; callers with a
+    real vocabulary pass theirs."""
+    NW, L = n_state_words(V), max(1 << int(W), MIN_LANES)
     EB = event_block() if eb is None else int(eb)
     budget = vmem_budget_bytes() if budget is None else int(budget)
-    frontier = NW * M * 4
-    rows = NW * int(K1) * V * 4
-    vmem = frontier * (1 + VMEM_SCRATCH_FACTOR) + rows
-    smem = 2 * EB * (2 + int(W)) * 4
+    frontier = ROW_BLOCK * NW * L * 4
+    vmem = frontier * (2 + VMEM_SCRATCH_FACTOR)
+    rows = NW * int(K1) * V * 4 * (1 if shared else ROW_BLOCK)
+    events = ROW_BLOCK * EB * (2 + int(W)) * 4
+    smem = 2 * (events + rows)
     return {"frontier_bytes": frontier, "rows_bytes": rows,
             "scratch_bytes": frontier * VMEM_SCRATCH_FACTOR,
             "vmem_bytes": vmem, "smem_bytes": smem,
-            "budget_bytes": budget, "fits": vmem <= budget}
+            "budget_bytes": budget, "smem_budget_bytes": SMEM_BUDGET_BYTES,
+            "fits": vmem <= budget and smem <= SMEM_BUDGET_BYTES}
 
 
-def pallas_supports(V: int, W: int,
-                    k1: Optional[int] = None) -> bool:
+def pallas_supports(V: int, W: int, k1: Optional[int] = None,
+                    shared: bool = True) -> bool:
     """Capability gate: the shapes this kernel hosts. Wider windows
     belong to the scan/wide/frontier routes; the router only ever
     PRICES pallas for shapes this admits. A config whose static VMEM
     footprint (vmem_plan) exceeds the budget is rejected HERE —
     before routing, pricing, or launch. ``k1`` is the real kind-
-    vocabulary bound (rows table [NW, K1, V]); callers that have the
-    encoded target in hand (the scheduler's route gate, the kernel
-    builder) MUST pass it — the default prices vmem_plan's nominal
-    bound, which a rich vocabulary can exceed many times over."""
+    vocabulary bound (rows table [NW, K1, V]) and ``shared`` whether
+    one table serves the whole batch; callers that have the encoded
+    target in hand (the scheduler's route gate, the kernel builder)
+    MUST pass both — the default prices vmem_plan's nominal bound,
+    which a rich vocabulary can exceed many times over."""
     if not (1 <= int(W) <= pallas_max_w()
             and 1 <= int(V) <= PALLAS_MAX_STATES):
         return False
     kw = {} if k1 is None else {"K1": int(k1)}
-    return bool(vmem_plan(V, W, **kw)["fits"])
+    return bool(vmem_plan(V, W, shared=shared, **kw)["fits"])
 
 
 def pallas_supports_resume() -> bool:
@@ -187,42 +186,63 @@ def pallas_supports_resume() -> bool:
 
 # --------------------------------------------------------- kernel body
 
-def _kernel_body(V: int, W: int, WL: int, EB: int, shared: bool):
+#: Batch rows per grid step: the sublane quantum, so every SMEM/VMEM
+#: block's second-to-last dim divides by 8 (the Mosaic tiling rule).
+ROW_BLOCK = 8
+
+#: Narrowest mask axis the kernel computes on: one full 128-lane vreg
+#: row. Narrower windows run at this width (a vreg op costs the same
+#: at 8 lanes as at 128) and the wrapper slices the frontier back.
+MIN_LANES = 128
+
+
+def _kernel_body(V: int, W: int, WL: int, EB: int, Wt: int, K1: int,
+                 shared: bool):
     """Build the Pallas kernel function for static (V, W, w_live,
-    event-block, target-sharing) bounds. Grid is (batch, event
-    blocks); per grid step the body advances one row's resident
-    frontier through EB events. The closure/completion math mirrors
-    ops.linearize line for line (same packed formulation), which is
-    what makes the parity gate bit-exact."""
+    event-block, slot-width, vocabulary, target-sharing) bounds. Grid
+    is (row blocks, event blocks); per grid step the body advances
+    ROW_BLOCK rows' resident frontiers through EB events. Event
+    tables and the packed transition rows live in SMEM (scalar reads
+    at dynamic indices); the frontier is per-word ``[1, L]`` int32
+    lane vectors in VMEM, and the mask-bit shuffles of the scan
+    kernel's reshapes become lane rolls plus selects. The closure and
+    completion math mirror ops.linearize word for word, which is what
+    makes the parity gate bit-exact."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     NW = n_state_words(V)
-    M = 1 << W
+    L = max(1 << W, MIN_LANES)
 
-    def _apply(Ft, rowvecs):
+    def _lanes():
+        return lax.broadcasted_iota(jnp.int32, (1, L), 1)
+
+    def _apply(Ft, coefs):
         # One slot application over every packed config: mirrors
-        # linearize._apply_slot + transition with F as per-word [M]
-        # arrays. ``rowvecs``: per-word [V] packed one-hot target rows
-        # for this slot's op (all-zero for empty slots => no-op).
+        # linearize._apply_slot + transition. ``coefs[i][w][s]``: the
+        # packed word w of state s's target under slot i's op (all
+        # zero for empty slots => no-op). Masks with bit i set take the
+        # successor computed at the mask with bit i clear (lane m-lo).
+        lanes = _lanes()
         out_words = list(Ft)
         for i in range(WL):
-            hi, lo = M >> (i + 1), 1 << i
-            Fr = [f.reshape(hi, 2, lo) for f in out_words]
-            src = [fr[:, 0, :] for fr in Fr]
+            lo = 1 << i
+            has_i = (lanes & lo) != 0
             new = [None] * NW
             for s in range(V):
-                bit = (src[s >> 5] >> jnp.uint32(s & 31)) & jnp.uint32(1)
+                bit = (out_words[s >> 5] >> (s & 31)) & 1
                 for w in range(NW):
-                    contrib = bit * rowvecs[i][w][s]
+                    contrib = bit * coefs[i][w][s]
                     new[w] = contrib if new[w] is None else new[w] | contrib
             out_words = [
-                jnp.concatenate([fr[:, :1, :],
-                                 fr[:, 1:, :] | n[:, None, :]], axis=1)
-                .reshape(M)
-                for fr, n in zip(Fr, new)]
+                jnp.where(has_i, f | pltpu.roll(n, lo, 1), f)
+                for f, n in zip(out_words, new)]
         return tuple(out_words)
 
-    def _closure(Ft, rowvecs):
+    def _any(x):
+        return jnp.max(jnp.where(x != 0, 1, 0)) > 0
+
+    def _closure(Ft, coefs):
         # Fixpoint in-kernel: monotone OR, <= live-slot iterations;
         # the while carry is the resident frontier itself.
         def cond(c):
@@ -230,36 +250,38 @@ def _kernel_body(V: int, W: int, WL: int, EB: int, shared: bool):
 
         def body(c):
             F0 = c[:NW]
-            Fn = _apply(F0, rowvecs)
-            changed = (Fn[0] != F0[0]).any()
+            Fn = _apply(F0, coefs)
+            diff = Fn[0] ^ F0[0]
             for a, b in zip(Fn[1:], F0[1:]):
-                changed = changed | (a != b).any()
-            return Fn + (changed,)
+                diff = diff | (a ^ b)
+            return Fn + (_any(diff),)
 
         out = lax.while_loop(cond, body, Ft + (jnp.bool_(True),))
         return out[:NW]
 
     def _complete(Ft, slot):
         # OK-completion for a DYNAMIC slot as a select over the WL
-        # static shift-half variants (linearize._complete_slot's
-        # branches, minus the lax.switch — predicated selects lower
-        # cleanly in Mosaic).
+        # static variants (linearize._complete_slot's branches): mask
+        # m without bit i takes the config at m+lo, masks with it die.
+        lanes = _lanes()
         out = None
         for i in range(WL):
-            hi, lo = M >> (i + 1), 1 << i
-            comp = []
-            for f in Ft:
-                fr = f.reshape(hi, 2, lo)
-                comp.append(jnp.concatenate(
-                    [fr[:, 1:, :], jnp.zeros_like(fr[:, 1:, :])],
-                    axis=1).reshape(M))
+            lo = 1 << i
+            keep = (lanes & lo) == 0
+            comp = tuple(jnp.where(keep, pltpu.roll(f, L - lo, 1), 0)
+                         for f in Ft)
             if out is None:
-                out = tuple(comp)
+                out = comp
             else:
                 sel = slot == i
                 out = tuple(jnp.where(sel, c, o)
                             for c, o in zip(comp, out))
         return out
+
+    def _coef(rows_ref, r, w, k, s):
+        if shared:
+            return rows_ref[w * K1 + k, s]
+        return rows_ref[r, (w * K1 + k) * V + s]
 
     def kernel(ev_type_ref, ev_slot_ref, ev_slots_ref, rows_ref,
                valid_ref, bad_ref, front_ref):
@@ -267,82 +289,67 @@ def _kernel_body(V: int, W: int, WL: int, EB: int, shared: bool):
 
         @pl.when(nb == 0)
         def _init():
-            valid_ref[0, 0] = jnp.int32(1)
-            bad_ref[0, 0] = jnp.int32(INT32_MAX)
-            row_ids = lax.broadcasted_iota(jnp.int32, (NW, M), 0)
-            col_ids = lax.broadcasted_iota(jnp.int32, (NW, M), 1)
-            front_ref[0] = jnp.where(
-                (row_ids == 0) & (col_ids == 0),
-                jnp.uint32(1), jnp.uint32(0))
+            for r in range(ROW_BLOCK):
+                valid_ref[r, 0] = jnp.int32(1)
+                bad_ref[r, 0] = jnp.int32(INT32_MAX)
+            w_ids = lax.broadcasted_iota(jnp.int32, (ROW_BLOCK, NW, L), 1)
+            m_ids = lax.broadcasted_iota(jnp.int32, (ROW_BLOCK, NW, L), 2)
+            front_ref[...] = jnp.where((w_ids == 0) & (m_ids == 0),
+                                       jnp.int32(1), jnp.int32(0))
 
-        def ev_step(e, carry):
-            typ = ev_type_ref[0, e]
-            # A decided row (first impossible completion already
-            # latched) skips every remaining event outright — the
-            # scan kernel has to idempotently no-op through them.
-            live = (valid_ref[0, 0] == 1) & (typ != EV_PAD)
+        def row_step(r, carry):
+            def ev_step(e, carry):
+                typ = ev_type_ref[r, e]
+                # A decided row (first impossible completion already
+                # latched) skips every remaining event outright — the
+                # scan kernel has to idempotently no-op through them.
+                live = (valid_ref[r, 0] == 1) & (typ != EV_PAD)
 
-            @pl.when(live)
-            def _():
-                slot = ev_slot_ref[0, e]
-                F = front_ref[0]
-                Ft = tuple(F[w] for w in range(NW))
-                rowvecs = []
-                for i in range(WL):
-                    k_i = ev_slots_ref[0, e, i]
-                    if shared:
-                        rowvecs.append(tuple(
-                            rows_ref[w, pl.ds(k_i, 1), :][0]
+                @pl.when(live)
+                def _():
+                    slot = ev_slot_ref[r, e]
+                    Ft = tuple(front_ref[r, pl.ds(w, 1), :]
+                               for w in range(NW))
+                    coefs = []
+                    for i in range(WL):
+                        k_i = ev_slots_ref[r, e * Wt + i]
+                        coefs.append(tuple(
+                            tuple(_coef(rows_ref, r, w, k_i, s)
+                                  for s in range(V))
                             for w in range(NW)))
-                    else:
-                        rowvecs.append(tuple(
-                            rows_ref[0, w, pl.ds(k_i, 1), :][0]
-                            for w in range(NW)))
-                Fc = _closure(Ft, rowvecs)
-                F_ok = _complete(Fc, slot)
-                union = F_ok[0]
-                for f in F_ok[1:]:
-                    union = union | f
-                is_ok = (typ == EV_OK) | (typ == EV_FUSED)
-                is_close = typ == EV_CLOSE
-                empty = is_ok & jnp.logical_not((union != 0).any())
+                    Fc = _closure(Ft, coefs)
+                    F_ok = _complete(Fc, slot)
+                    union = F_ok[0]
+                    for f in F_ok[1:]:
+                        union = union | f
+                    is_ok = (typ == EV_OK) | (typ == EV_FUSED)
+                    is_close = typ == EV_CLOSE
+                    empty = is_ok & jnp.logical_not(_any(union))
 
-                @pl.when(empty)
-                def _fail():
-                    # Latch the pre-completion closure — the frontier
-                    # the host decodes the Knossos-parity
-                    # counterexample sample from.
-                    valid_ref[0, 0] = jnp.int32(0)
-                    bad_ref[0, 0] = (nb * EB + e).astype(jnp.int32)
-                    for w in range(NW):
-                        front_ref[0, w] = Fc[w]
+                    @pl.when(empty)
+                    def _fail():
+                        # Latch the pre-completion closure — the
+                        # frontier the host decodes the Knossos-parity
+                        # counterexample sample from.
+                        valid_ref[r, 0] = jnp.int32(0)
+                        bad_ref[r, 0] = nb * EB + e
+                        for w in range(NW):
+                            front_ref[r, pl.ds(w, 1), :] = Fc[w]
 
-                @pl.when(jnp.logical_not(empty))
-                def _advance():
-                    for w in range(NW):
-                        front_ref[0, w] = jnp.where(
-                            is_ok, F_ok[w],
-                            jnp.where(is_close, Fc[w], Ft[w]))
+                    @pl.when(jnp.logical_not(empty))
+                    def _advance():
+                        for w in range(NW):
+                            front_ref[r, pl.ds(w, 1), :] = jnp.where(
+                                is_ok, F_ok[w],
+                                jnp.where(is_close, Fc[w], Ft[w]))
 
-            return carry
+                return carry
 
-        lax.fori_loop(0, EB, ev_step, jnp.int32(0))
+            return lax.fori_loop(0, EB, ev_step, carry)
+
+        lax.fori_loop(0, ROW_BLOCK, row_step, jnp.int32(0))
 
     return kernel
-
-
-def _compiler_params(pltpu):
-    """Best-effort Mosaic params: batch rows are independent grid
-    steps; event blocks of one row must run in order (the resident
-    frontier carries across them)."""
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=("parallel", "arbitrary"))
-            except Exception:
-                continue
-    return None
 
 
 def make_pallas_kernel(V: int, W: int, *, shared_target: bool = False,
@@ -358,85 +365,91 @@ def make_pallas_kernel(V: int, W: int, *, shared_target: bool = False,
     from jax.experimental.pallas import tpu as pltpu
 
     NW, M = n_state_words(V), 1 << W
+    L = max(M, MIN_LANES)
+    RB = ROW_BLOCK
     WL = W if w_live is None else max(1, min(int(w_live), W))
     EB = event_block()
     if interpret is None:
         interpret = pallas_mode() != "compiled"
-    kernel = _kernel_body(V, W, WL, EB, shared_target)
     kw: dict = {}
     if not interpret:
-        params = _compiler_params(pltpu)
-        if params is not None:
-            kw["compiler_params"] = params
+        # Row blocks are independent grid steps; event blocks of one
+        # row block run in order (the resident frontier carries).
+        kw["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"))
 
     def check(ev_type, ev_slot, ev_slots, target):
-        ev_type = ev_type.astype(jnp.int32)
-        ev_slot = ev_slot.astype(jnp.int32)
-        ev_slots = ev_slots.astype(jnp.int32)
         B, N = ev_type.shape
-        K1 = target.shape[-2]
+        Wt = ev_slots.shape[2]
+        K1 = int(target.shape[-2])
         # The launch gate with the REAL rows table: the build-time
         # pallas_supports assert prices the nominal K1 bound, but the
         # actual kind vocabulary arrives here, per shape — an
         # over-budget config must fail loudly at trace time, never
         # reach the pallas_call.
-        plan = vmem_plan(V, W, K1=int(K1))
+        plan = vmem_plan(V, W, K1=K1, shared=shared_target)
         if not plan["fits"]:
             raise ValueError(
-                f"pallas config V={V} W={W} K1={int(K1)} needs "
-                f"{plan['vmem_bytes']} B VMEM (> budget "
-                f"{plan['budget_bytes']}) — rejected before launch")
-        Np = ((N + EB - 1) // EB) * EB
-        if Np != N:
-            # EV_PAD steps are no-ops; slot tables pad to the
-            # all-invalid sentinel row like every other pad path.
-            ev_type = jnp.pad(ev_type, ((0, 0), (0, Np - N)))
-            ev_slot = jnp.pad(ev_slot, ((0, 0), (0, Np - N)))
-            ev_slots = jnp.pad(ev_slots,
-                               ((0, 0), (0, Np - N), (0, 0)),
-                               constant_values=K1 - 1)
-        Wt = ev_slots.shape[2]
-        packed = pack_rows(target, V)
+                f"pallas config V={V} W={W} K1={K1} needs "
+                f"{plan['vmem_bytes']} B VMEM / {plan['smem_bytes']} B "
+                f"SMEM (> budgets {plan['budget_bytes']} / "
+                f"{plan['smem_budget_bytes']}) — rejected before launch")
+        Bp = -(-B // RB) * RB
+        Np = -(-N // EB) * EB
+        # EV_PAD steps are no-ops; slot tables pad to the all-invalid
+        # sentinel row like every other pad path. Pad rows are EV_PAD
+        # throughout and sliced off below.
+        ev_type = jnp.pad(ev_type.astype(jnp.int32),
+                          ((0, Bp - B), (0, Np - N)))
+        ev_slot = jnp.pad(ev_slot.astype(jnp.int32),
+                          ((0, Bp - B), (0, Np - N)))
+        ev_slots = jnp.pad(ev_slots.astype(jnp.int32),
+                           ((0, Bp - B), (0, Np - N), (0, 0)),
+                           constant_values=K1 - 1).reshape(Bp, Np * Wt)
+        packed = [lax.bitcast_convert_type(p, jnp.int32)
+                  for p in pack_rows(target, V)]
+        smem = pltpu.SMEM
         if shared_target:
-            rows = jnp.stack(packed)                      # [NW, K1, V]
-            rows_spec = pl.BlockSpec((NW, K1, V),
-                                     lambda b, nb: (0, 0, 0),
-                                     memory_space=pltpu.VMEM)
+            rows = jnp.concatenate(packed, axis=0)       # [NW*K1, V]
+            rows_spec = pl.BlockSpec((NW * K1, V), lambda b, nb: (0, 0),
+                                     memory_space=smem)
         else:
-            rows = jnp.stack(packed, axis=1)           # [B, NW, K1, V]
-            rows_spec = pl.BlockSpec((1, NW, K1, V),
-                                     lambda b, nb: (b, 0, 0, 0),
-                                     memory_space=pltpu.VMEM)
-        grid = (B, Np // EB)
+            rows = jnp.stack(packed, axis=1).reshape(B, NW * K1 * V)
+            rows = jnp.pad(rows, ((0, Bp - B), (0, 0)))
+            rows_spec = pl.BlockSpec((RB, NW * K1 * V),
+                                     lambda b, nb: (b, 0),
+                                     memory_space=smem)
+        kernel = _kernel_body(V, W, WL, EB, Wt, K1, shared_target)
         valid_i, bad, front = pl.pallas_call(
             kernel,
-            grid=grid,
+            grid=(Bp // RB, Np // EB),
             in_specs=[
-                pl.BlockSpec((1, EB), lambda b, nb: (b, nb),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, EB), lambda b, nb: (b, nb),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, EB, Wt), lambda b, nb: (b, nb, 0),
-                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((RB, EB), lambda b, nb: (b, nb),
+                             memory_space=smem),
+                pl.BlockSpec((RB, EB), lambda b, nb: (b, nb),
+                             memory_space=smem),
+                pl.BlockSpec((RB, EB * Wt), lambda b, nb: (b, nb),
+                             memory_space=smem),
                 rows_spec,
             ],
             out_shape=(
-                jax.ShapeDtypeStruct((B, 1), jnp.int32),
-                jax.ShapeDtypeStruct((B, 1), jnp.int32),
-                jax.ShapeDtypeStruct((B, NW, M), jnp.uint32),
+                jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+                jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+                jax.ShapeDtypeStruct((Bp, NW, L), jnp.int32),
             ),
             out_specs=(
-                pl.BlockSpec((1, 1), lambda b, nb: (b, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1), lambda b, nb: (b, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, NW, M), lambda b, nb: (b, 0, 0),
+                pl.BlockSpec((RB, 1), lambda b, nb: (b, 0),
+                             memory_space=smem),
+                pl.BlockSpec((RB, 1), lambda b, nb: (b, 0),
+                             memory_space=smem),
+                pl.BlockSpec((RB, NW, L), lambda b, nb: (b, 0, 0),
                              memory_space=pltpu.VMEM),
             ),
             interpret=interpret,
             **kw,
         )(ev_type, ev_slot, ev_slots, rows)
-        return valid_i[:, 0] != 0, bad[:, 0], front
+        front = lax.bitcast_convert_type(front[:B, :, :M], jnp.uint32)
+        return valid_i[:B, 0] != 0, bad[:B, 0], front
 
     return jax.jit(check)
 
@@ -497,8 +510,9 @@ def probe_rates(rows: int = 32, events: int = 64, V: int = 4,
     own units (frontier-lane events per second — the ``n_events * 2^W
     / rate`` basis price_wgl divides by). Returns
     ``{"lane_ops_per_s", "pallas_lane_ops_per_s", "probe_s", "mode",
-    "parity"}``; the pallas rate is 0.0 when the kernel is
-    unavailable or failed, which prices it out of every route."""
+    "parity"}``; the pallas rate is 0.0 when the kernel is disabled or
+    disagrees with the scan, which prices it out of every route. A
+    kernel that fails to compile or run raises."""
     from .linearize import get_kernel
 
     args = make_probe_batch(V, W, rows, events)
@@ -510,20 +524,16 @@ def probe_rates(rows: int = 32, events: int = 64, V: int = 4,
     out["lane_ops_per_s"] = basis / max(_time_kernel(xk, args, repeats),
                                         1e-9)
     if pallas_available() and pallas_supports(V, W):
-        try:
-            pk = get_pallas_kernel(V, W, shared_target=True)
-            out["pallas_lane_ops_per_s"] = basis / max(
-                _time_kernel(pk, args, repeats), 1e-9)
-            xv, xb, xf = (np.asarray(a) for a in xk(*args))
-            pv, pb, pf = (np.asarray(a) for a in pk(*args))
-            out["parity"] = bool(
-                (xv == pv).all() and (xb == pb).all()
-                and (xf == pf).all())
-            if out["parity"] is False:
-                # A kernel that disagrees with the scan must never win
-                # a route on speed.
-                out["pallas_lane_ops_per_s"] = 0.0
-        except Exception:
+        pk = get_pallas_kernel(V, W, shared_target=True)
+        out["pallas_lane_ops_per_s"] = basis / max(
+            _time_kernel(pk, args, repeats), 1e-9)
+        xv, xb, xf = (np.asarray(a) for a in xk(*args))
+        pv, pb, pf = (np.asarray(a) for a in pk(*args))
+        out["parity"] = bool(
+            (xv == pv).all() and (xb == pb).all() and (xf == pf).all())
+        if out["parity"] is False:
+            # A kernel that disagrees with the scan must never win a
+            # route on speed.
             out["pallas_lane_ops_per_s"] = 0.0
     out["probe_s"] = round(time.perf_counter() - t_all, 4)
     return out

@@ -258,40 +258,44 @@ _CACHE_WIRED = False
 _CACHE_LOCK = threading.Lock()
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> Optional[str]:
+#: Where the compile cache lives when $JAX_COMPILATION_CACHE_DIR is
+#: unset: a fixed path inside the checkout (gitignored). The path is
+#: part of what makes a cache entry findable again, so it never moves.
+DEFAULT_CACHE_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def cache_root() -> str:
+    """The one compile-cache root: $JAX_COMPILATION_CACHE_DIR when set
+    (jax reads it itself), else DEFAULT_CACHE_ROOT. The AOT shipping
+    dir callers opt into lives under it (``<root>/aot``)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_ROOT
+
+
+def enable_compilation_cache() -> Optional[str]:
     """Wire jax's persistent compilation cache (idempotent).
 
     Repeat bench runs and store rechecks then deserialize their kernels
     instead of recompiling — near-zero compile on the second process.
-    Resolution order: an already-configured ``jax_compilation_cache_dir``
-    wins (e.g. a caller that set its own path); then ``cache_dir``; then
-    $JT_COMPILE_CACHE_DIR; then ~/.cache/jepsen_tpu/xla. Set
-    JT_COMPILE_CACHE=0 to disable. Returns the effective dir or None.
-    """
+    The directory is cache_root(); set JT_COMPILE_CACHE=0 to disable.
+    Returns the effective dir or None."""
     global _CACHE_WIRED
     if os.environ.get("JT_COMPILE_CACHE") == "0":
         return None
     with _CACHE_LOCK:
         import jax
-        current = getattr(jax.config, "jax_compilation_cache_dir", None)
-        if _CACHE_WIRED or current:
-            return current
-        path = (cache_dir or os.environ.get("JT_COMPILE_CACHE_DIR")
-                or os.path.join(os.path.expanduser("~"), ".cache",
-                                "jepsen_tpu", "xla"))
-        try:
-            os.makedirs(path, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", path)
-            # Cache every kernel, however small/fast to compile: the
-            # checker's kernels are many and individually cheap — the
-            # 13-kernel bench mix is exactly the long tail the default
-            # thresholds would skip.
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0)
-        except Exception:
-            return None     # older jax without the knobs: cache is off
+        path = jax.config.jax_compilation_cache_dir or cache_root()
+        if _CACHE_WIRED:
+            return path
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+        # Cache every kernel, however small/fast to compile: the
+        # checker's kernels are many and individually cheap — the
+        # 13-kernel bench mix is exactly the long tail the default
+        # thresholds would skip.
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         _CACHE_WIRED = True
         return path
 
@@ -491,7 +495,11 @@ def _aot_read(path: str):
         tag, payload, in_tree, out_tree = pickle.load(f)
     if tag != _aot_env_tag():
         return None
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    import jax
+    # Every shipped kernel is a single-device jit: load it onto the
+    # default device only (unpinned, jax loads onto every device).
+    return se.deserialize_and_load(payload, in_tree, out_tree,
+                                   execution_devices=jax.devices()[:1])
 
 
 def _aot_load(key: Tuple):
@@ -1460,7 +1468,8 @@ class BucketScheduler:
                                  router_prefers_pallas)
         if not (pallas_available()
                 and pallas_supports(batch.V, batch.W,
-                                    k1=batch.target.shape[1])):
+                                    k1=batch.target.shape[1],
+                                    shared=batch.shared_target)):
             return False
         if self.wgl_backend == "pallas":
             return True

@@ -29,11 +29,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 from ..ops.encode import EV_CLOSE, EV_FUSED, EV_OK
 from ..ops.linearize import (INT32_MAX, MAX_PACKED_STATES, _apply_slot,
@@ -152,17 +149,15 @@ def make_frontier_kernel(V: int, W: int, D: int,
         F0 = (Fz[0].at[0].set(jnp.where(ax == 0, jnp.uint32(1),
                                         jnp.uint32(0))),) + Fz[1:]
         # The scan consumes data-sharded events, so its carry is varying
-        # over "data" — widen the initial carry's type to match. jax
-        # without varying types (< pcast) skips the cast: the shard_map
-        # below runs unreplicated (check_rep=False) there, so carry
-        # types need no widening.
+        # over "data" — widen the initial carry's type to match.
         extra = tuple(a for a in sync_axes if a != "frontier")
-        if hasattr(lax, "pcast"):
-            pcast = lambda x: lax.pcast(x, extra, to="varying")  # noqa: E731
-            pcast_all = lambda x: lax.pcast(  # noqa: E731
-                x, tuple(sync_axes), to="varying")
-        else:
-            pcast = pcast_all = lambda x: x  # noqa: E731
+
+        def pcast(x):
+            return lax.pcast(x, extra, to="varying")
+
+        def pcast_all(x):
+            return lax.pcast(x, tuple(sync_axes), to="varying")
+
         # Fbad is written from Fc (varying over EVERY mesh axis — F0
         # derives from axis_index), so its initial value must be too.
         carry = (tuple(pcast(f) for f in F0),
@@ -196,14 +191,8 @@ def frontier_sharded_kernel(V: int, W: int, mesh: Mesh,
                     in_axes=(0, 0, 0, None if shared_target else 0))
     ev = P("data", None)
     tgt = P(None, None) if shared_target else P("data", None, None)
-    kw = {}
-    if not hasattr(lax, "pcast"):
-        # Pre-varying-types jax: the replication checker can't see
-        # through the axis_index-seeded carry + collective while_loop;
-        # the out_specs still pin the sharding contract.
-        kw["check_rep"] = False
     sharded = shard_map(kern, mesh=mesh,
                         in_specs=(ev, ev, P("data", None, None), tgt),
                         out_specs=(P("data"), P("data"),
-                                   P("data", None, "frontier")), **kw)
+                                   P("data", None, "frontier")))
     return jax.jit(sharded)

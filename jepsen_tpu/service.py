@@ -240,6 +240,8 @@ class ServiceWorker(OnlineDaemon):
             else _env_f("JT_SERVICE_PLACEMENT_PATIENCE_S", 2 * self.ttl))
         self.rebalance_factor = float(rebalance_factor)
         self._rates = dict(rates) if rates else None
+        from .provision import device_identity
+        self._device = device_identity()
         # Lease bookkeeping: {key: {"gen", "path", "renewed"}}.
         self.owned: Dict[Tuple[str, str], dict] = {}
         # Heartbeats run on their own daemon thread (started by
@@ -736,6 +738,7 @@ class ServiceWorker(OnlineDaemon):
         rec = {
             "service": SERVICE_MAGIC, "worker": self.worker_id,
             "pid": os.getpid(), "hb": time.time(),
+            "device": self._device,
             "usage": usage, "caps": self._caps(),
             "stats": dict(self.stats),
             "takeover_latency_s": list(self.takeover_latencies),

@@ -89,14 +89,18 @@ def test_bench_emits_contract_json():
     # Fused/renumbered-scan instrumentation (ISSUE 2 acceptance).
     assert d["fusion_ratio"] >= 1.0
     assert d["mean_live_slots"] > 0
-    assert d["roofline"]["vpu_util"] >= 0
+    # Off the chip every device figure names the CPU and every
+    # utilization reads null: not measured.
+    assert d["device"]["platform"] == "cpu" and d["device"]["count"] >= 1
+    assert d["roofline"]["vpu_util"] is None
+    assert d["roofline"]["hbm_util"] is None
     assert d["roofline"]["closure_iters_total"] > 0
     assert d["roofline"]["source_events_per_s"] > 0
     # Graph-checker section (ISSUE 4 acceptance): MXU op-model figures
     # next to the WGL VPU metrics.
     g = d["graph_checker"]
     assert g["graphs"] == 32 and g["graphs_per_s"] > 0
-    assert g["closure_matmuls"] > 0 and g["mxu_util"] >= 0
+    assert g["closure_matmuls"] > 0 and g["mxu_util"] is None
     assert g["anomalies"] >= 1
     assert g["vertex_buckets"]
     assert g["resilience"]["quarantined_rows"] == 0
@@ -184,6 +188,7 @@ def test_bench_emits_contract_json():
         assert p["e2e_s"] > 0 and p["hist_per_s"] > 0
         assert p["speedup"] > 0 and p["parallel_efficiency"] > 0
         assert 1 <= p["spawned"] <= p["workers"]
+        assert p["worker_platforms"] == ["cpu"]
     assert fl["points"][0]["speedup"] == 1.0
     assert fl["host_cores"] >= 1
     assert isinstance(fl["monotone"], bool)
@@ -192,9 +197,10 @@ def test_bench_emits_contract_json():
     tblw = {row["W"]: row["backend"] for row in fl["router_table"]}
     # Past max_device_w the 2^W frontier backends are ineligible: only
     # host-oracle and the r17 peel backend may appear, and a probed dc
-    # rate routes W=20 to wgl-dc.
-    assert tblw[4] == "wgl-device" and tblw[20] in ("wgl-dc",
-                                                    "host-oracle")
+    # rate routes W=20 to wgl-dc. At W=4 a device backend wins; which
+    # one follows the measured rates of this host.
+    assert tblw[4] in ("wgl-device", "wgl-dc", "wgl-pallas")
+    assert tblw[20] in ("wgl-dc", "host-oracle")
     # Online checker-daemon section (ISSUE 9 acceptance): live-tailed
     # verdicts while the histories are still being written, plus the
     # forced overload burst degrading through the ladder without
@@ -239,6 +245,7 @@ def test_bench_emits_contract_json():
         assert p["ttfv_p99_s"] is not None
         assert p["tenants_within_slo"] == 2
         assert p["valid_ok"] is True
+        assert p["worker_platforms"] == ["cpu"]
     tk = sv["takeover"]
     assert tk["tenants"] == 2 and tk["killed_owned"] >= 1
     assert tk["measured"] == tk["killed_owned"]
@@ -275,7 +282,7 @@ def test_bench_emits_contract_json():
     assert [p["W"] for p in bc["points"]] == [4]
     p0 = bc["points"][0]
     assert p0["rows"] == 8 and p0["xla_hist_per_s"] > 0
-    assert p0["winner"] in ("xla", "pallas")
+    assert p0["winner"] in ("xla", "pallas", "dc")
     if bc["mode"] != "off":
         assert p0["pallas_hist_per_s"] > 0
         assert p0["pallas_speedup"] > 0
