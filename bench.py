@@ -495,8 +495,8 @@ def main(compare: dict = None, tolerance: float = 0.20) -> int:
 
     # Streamed end-to-end: the columnar encode walk chunks into groups
     # that overlap device dispatch (one pipeline from raw columns to
-    # verdicts), which is where time-to-first-verdict and the pipeline
-    # overlap ratio are measured. Encode work re-runs inside, so this
+    # verdicts), which is where time-to-first-verdict is measured.
+    # Encode work re-runs inside, so this
     # figure is directly comparable to t_e2e.
     def run_streamed():
         from concurrent.futures import ThreadPoolExecutor
@@ -2279,7 +2279,6 @@ def main(compare: dict = None, tolerance: float = 0.20) -> int:
             # Streamed pipeline figures (columnar encode chunked and
             # overlapped with dispatch/decode end-to-end).
             "t_first_verdict_s": streamed_stats.get("t_first_verdict_s"),
-            "overlap_ratio": streamed_stats.get("overlap_ratio"),
             "streamed_e2e_rate": round(streamed_rate, 2),
             "streamed_e2e_time_s": round(t_streamed, 3),
             "streamed_histories": n_streamed,

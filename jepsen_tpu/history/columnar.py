@@ -31,6 +31,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import telemetry
 from .ops import Op, invoke_op, ok_op, info_op
 
 # Line type codes.
@@ -322,19 +323,19 @@ def ops_to_columnar(model, histories: Sequence[Sequence[Op]], *,
     pure-Python twin); the identity-drop + padding pass is vectorized
     numpy either way.
     """
-    vocab, all_kinds = _seed_vocab(kinds)
-
-    ext = None
-    if native:
-        from ..native import ingest
-        ext = ingest()
-    if ext is not None:
-        histories = [h if isinstance(h, (list, tuple)) else list(h)
-                     for h in histories]
-        arrays = _from_bufs(ext.walk(histories, vocab, all_kinds))
-    else:
-        arrays = _walk_py(histories, vocab, all_kinds)
-    return _pack_walk(model, arrays, all_kinds, max_states)
+    with telemetry.span("columnar.convert"):
+        vocab, all_kinds = _seed_vocab(kinds)
+        ext = None
+        if native:
+            from ..native import ingest
+            ext = ingest()
+        if ext is not None:
+            histories = [h if isinstance(h, (list, tuple)) else list(h)
+                         for h in histories]
+            arrays = _from_bufs(ext.walk(histories, vocab, all_kinds))
+        else:
+            arrays = _walk_py(histories, vocab, all_kinds)
+        return _pack_walk(model, arrays, all_kinds, max_states)
 
 
 def columnar_to_ops(cols: ColumnarOps, row: int,

@@ -58,6 +58,7 @@ from jax import lax
 
 import numpy as np
 
+from .. import telemetry
 from ..history.ops import Op
 from ..models.core import Model
 from .encode import (EV_CLOSE, EV_FUSED, EV_OK, EncodedBatch,
@@ -1305,9 +1306,10 @@ class _NativeTailWorker:
             if idxs is None:
                 return out
             try:
-                rs = check_batch_native(
-                    self.model,
-                    [columnar_to_ops(self.cols, i) for i in idxs])
+                with telemetry.span("native.tail", rows=len(idxs)):
+                    rs = check_batch_native(
+                        self.model,
+                        [columnar_to_ops(self.cols, i) for i in idxs])
             except Exception:
                 out.extend((i, None) for i in idxs)
                 continue
@@ -1502,7 +1504,9 @@ def _check_columnar_impl(model: Model, cols, *, max_slots, host_fallback,
         stream = sch.run(groups)
     else:
         DIVERTED = object()       # never yielded by the threaded path
-        buckets, fails = encode_columnar(space, cols, max_slots=eff_slots)
+        with telemetry.span("encode.walk", rows=cols.batch):
+            buckets, fails = encode_columnar(space, cols,
+                                             max_slots=eff_slots)
         failures.extend(fails)
         if tail is not None:
             small = [b for b in buckets
@@ -1534,36 +1538,37 @@ def _check_columnar_impl(model: Model, cols, *, max_slots, host_fallback,
         fused_refine.extend(int(idx[x]) for x in fb)
         fused_local = set(fb.tolist())
         if details:
-            for bi, row in enumerate(batch.indices):
-                if sch is not None and row in sch.quarantined:
-                    continue       # placeholder; host-decided below
-                if details == "invalid" and bool(v[bi]):
-                    # Lazy mode's valid rows stay the bare contract
-                    # dict; provenance appears only when it carries
-                    # information (the row left the happy path).
-                    results[row] = {"valid": True}
-                    if sch is not None and row in sch.row_provenance:
+            with telemetry.span("decode.details", rows=len(idx)):
+                for bi, row in enumerate(batch.indices):
+                    if sch is not None and row in sch.quarantined:
+                        continue       # placeholder; host-decided below
+                    if details == "invalid" and bool(v[bi]):
+                        # Lazy mode's valid rows stay the bare contract
+                        # dict; provenance appears only when it carries
+                        # information (the row left the happy path).
+                        results[row] = {"valid": True}
+                        if sch is not None and row in sch.row_provenance:
+                            results[row]["provenance"] = \
+                                sch.row_provenance[row]
+                        continue
+                    if bi in fused_local:
+                        continue               # refined below
+                    # The columnar form already applied the prepared-history
+                    # contract (value propagation + identity drop) at
+                    # conversion: reconstruct with propagated invokes and
+                    # skip both complete() and the per-op drop recompute —
+                    # the decode walk still sees exactly the encoder's op
+                    # kinds and slot assignment. Renumbered rows decode
+                    # against their own sub-space (batch.spaces).
+                    ops = columnar_to_ops(cols, row, propagated=True)
+                    sp = batch.spaces[bi] if batch.spaces else space
+                    results[row] = _decode_result(
+                        sp, ops, bool(v[bi]),
+                        int(bad[row]) if not bool(v[bi]) else -1, front[bi],
+                        predropped=True)
+                    if sch is not None:
                         results[row]["provenance"] = \
-                            sch.row_provenance[row]
-                    continue
-                if bi in fused_local:
-                    continue               # refined below
-                # The columnar form already applied the prepared-history
-                # contract (value propagation + identity drop) at
-                # conversion: reconstruct with propagated invokes and
-                # skip both complete() and the per-op drop recompute —
-                # the decode walk still sees exactly the encoder's op
-                # kinds and slot assignment. Renumbered rows decode
-                # against their own sub-space (batch.spaces).
-                ops = columnar_to_ops(cols, row, propagated=True)
-                sp = batch.spaces[bi] if batch.spaces else space
-                results[row] = _decode_result(
-                    sp, ops, bool(v[bi]),
-                    int(bad[row]) if not bool(v[bi]) else -1, front[bi],
-                    predropped=True)
-                if sch is not None:
-                    results[row]["provenance"] = \
-                        sch.row_provenance.get(row, "device")
+                            sch.row_provenance.get(row, "device")
     if sch is not None:
         # Rows the degradation ladder quarantined carry inert
         # placeholder verdicts in the stream: re-decide each through
@@ -1571,8 +1576,9 @@ def _check_columnar_impl(model: Model, cols, *, max_slots, host_fallback,
         # a real verdict under any fault schedule.
         failures.extend((i, f"quarantined: {why}")
                         for i, why in sch.quarantined.items())
-    if tail is not None:
-        for i, r in tail.finish():
+    with telemetry.span("host.tail"):
+        done = tail.finish() if tail is not None else []
+        for i, r in done:
             if r is None:                    # native engine unavailable
                 failures.append((i, "small bucket"))
                 continue
@@ -1588,44 +1594,68 @@ def _check_columnar_impl(model: Model, cols, *, max_slots, host_fallback,
                 results[i].setdefault("provenance", "host-fallback")
             if sink is not None:
                 _sink_verdict(sink, i, r)
-    if fused_refine:
-        # Exact bad-index/counterexample recovery for rows that failed
-        # inside a fused run. Verdict-only callers ride the native
-        # batch engine when it exists; details callers take the host
-        # engine's full result (the parity shape).
-        hs = [columnar_to_ops(cols, i) for i in fused_refine]
-        rs = None
-        if not details:
-            try:
-                from ..native import check_batch_native
-                rs = check_batch_native(model, hs)
-            except Exception:
-                rs = None
-        if rs is None:
-            rs = [host_fallback(model, h) for h in hs]
-        for i, r in zip(fused_refine, rs):
-            valid[i] = r["valid"] is True
+        if fused_refine:
+            # Exact bad-index/counterexample recovery for rows that failed
+            # inside a fused run. Verdict-only callers ride the native
+            # batch engine when it exists; details callers take the host
+            # engine's full result (the parity shape).
+            hs = [columnar_to_ops(cols, i) for i in fused_refine]
+            rs = None
+            if not details:
+                try:
+                    from ..native import check_batch_native
+                    rs = check_batch_native(model, hs)
+                except Exception:
+                    rs = None
+            if rs is None:
+                rs = [host_fallback(model, h) for h in hs]
+            for i, r in zip(fused_refine, rs):
+                valid[i] = r["valid"] is True
+                if r["valid"] is False:
+                    bad[i] = r["op"].get("index", -1)
+                if details:
+                    r.setdefault("provenance", "host-fallback")
+                    results[i] = r
+                if sink is not None:
+                    _sink_verdict(sink, i, r)
+        for row, reason in failures:
+            r = host_fallback(model, columnar_to_ops(cols, row))
+            valid[row] = r["valid"] is True
             if r["valid"] is False:
-                bad[i] = r["op"].get("index", -1)
+                bad[row] = r["op"].get("index", -1)
             if details:
+                r.setdefault("fallback", reason)
                 r.setdefault("provenance", "host-fallback")
-                results[i] = r
+                results[row] = r
             if sink is not None:
-                _sink_verdict(sink, i, r)
-    for row, reason in failures:
-        r = host_fallback(model, columnar_to_ops(cols, row))
-        valid[row] = r["valid"] is True
-        if r["valid"] is False:
-            bad[row] = r["op"].get("index", -1)
-        if details:
-            r.setdefault("fallback", reason)
-            r.setdefault("provenance", "host-fallback")
-            results[row] = r
-        if sink is not None:
-            _sink_verdict(sink, row, r)
+                _sink_verdict(sink, row, r)
+    _count_subs(cols.batch, sch, {i for i, _ in done}
+                | {i for i, _ in failures} | set(fused_refine))
     if details:
         return results
     return valid, bad
+
+
+_SUBS_COUNTERS: Dict[str, object] = {}
+
+
+def _count_subs(n_rows: int, sch, host_rows: set) -> None:
+    """``check.subs{provenance=...}``: the rows one check finalized under
+    each provenance (``device`` when no other applies), one increment
+    per value."""
+    counts = {"host-fallback": len(host_rows)}
+    if sch is not None:
+        for row, prov in sch.row_provenance.items():
+            if row not in host_rows:
+                counts[prov] = counts.get(prov, 0) + 1
+    counts["device"] = n_rows - sum(counts.values())
+    for prov, n in counts.items():
+        if n:
+            c = _SUBS_COUNTERS.get(prov)
+            if c is None:
+                c = _SUBS_COUNTERS[prov] = telemetry.REGISTRY.counter(
+                    "check.subs", provenance=prov)
+            c.inc(n)
 
 
 def _sink_verdict(sink, row: int, r: dict) -> None:

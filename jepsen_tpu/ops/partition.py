@@ -41,6 +41,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import telemetry
 from ..history.ops import Op
 from ..independent import KV, history_keys, is_kv, subhistory
 
@@ -104,7 +105,6 @@ def partition_columnar(cols) -> Optional[PartitionedBatch]:
     O(keys * batch * lines) numpy, far below the encode walk it feeds.
     """
     from ..history.columnar import PAD, ColumnarOps
-    from .. import telemetry
     key = getattr(cols, "key", None)
     if key is None:
         return None
@@ -260,25 +260,26 @@ def partition_histories(histories: Sequence[List[Op]], *,
     Histories without keys pass through as a single sub (key None); op
     identity (and so ``op.index``) is preserved by the shared
     strainer, which is what maps bad ops back through the partition."""
-    if not force and not any(history_has_kv(h) for h in histories):
-        return None
-    if force and not any(history_keys(h) for h in histories):
-        return None
-    subs: List[List[Op]] = []
-    sub_hist: List[int] = []
-    sub_key: List = []
-    for i, h in enumerate(histories):
-        ks = history_keys(h)
-        if not ks:
-            subs.append(list(h))
-            sub_hist.append(i)
-            sub_key.append(None)
-            continue
-        for k in ks:
-            subs.append(subhistory(k, h))
-            sub_hist.append(i)
-            sub_key.append(k)
-    return subs, np.asarray(sub_hist, np.int64), sub_key
+    with telemetry.span("partition.histories"):
+        if not force and not any(history_has_kv(h) for h in histories):
+            return None
+        if force and not any(history_keys(h) for h in histories):
+            return None
+        subs: List[List[Op]] = []
+        sub_hist: List[int] = []
+        sub_key: List = []
+        for i, h in enumerate(histories):
+            ks = history_keys(h)
+            if not ks:
+                subs.append(list(h))
+                sub_hist.append(i)
+                sub_key.append(None)
+                continue
+            for k in ks:
+                subs.append(subhistory(k, h))
+                sub_hist.append(i)
+                sub_key.append(k)
+        return subs, np.asarray(sub_hist, np.int64), sub_key
 
 
 # ------------------------------------------------------ recombination
@@ -295,19 +296,20 @@ def recombine_verdicts(valid: np.ndarray, bad: np.ndarray,
     space, see partition_columnar). Returns ``(valid, bad,
     bad_key)`` with ``bad_key`` mapping invalid history rows to the
     witnessing key."""
-    from .linearize import INT32_MAX
-    hv = np.ones(n_histories, bool)
-    hb = np.full(n_histories, INT32_MAX, np.int32)
-    bad_key: Dict[int, object] = {}
-    inv = np.flatnonzero(~np.asarray(valid))
-    for s in inv.tolist():
-        h = int(sub_history[s])
-        hv[h] = False
-        b = int(np.asarray(bad)[s])
-        if b < hb[h]:
-            hb[h] = b
-            bad_key[h] = sub_key[s]
-    return hv, hb, bad_key
+    with telemetry.span("recombine", rows=n_histories):
+        from .linearize import INT32_MAX
+        hv = np.ones(n_histories, bool)
+        hb = np.full(n_histories, INT32_MAX, np.int32)
+        bad_key: Dict[int, object] = {}
+        inv = np.flatnonzero(~np.asarray(valid))
+        for s in inv.tolist():
+            h = int(sub_history[s])
+            hv[h] = False
+            b = int(np.asarray(bad)[s])
+            if b < hb[h]:
+                hb[h] = b
+                bad_key[h] = sub_key[s]
+        return hv, hb, bad_key
 
 
 def recombine_details(results: Sequence[dict], sub_history: np.ndarray,
@@ -319,46 +321,47 @@ def recombine_details(results: Sequence[dict], sub_history: np.ndarray,
     op, configs, provenance — plus ``independent_key`` (the witness
     key) and ``failures`` (every invalid key), matching the lifted
     per-key checker's reporting."""
-    from ..checkers.core import merge_valid
-    by_hist: Dict[int, List[int]] = {}
-    for s, h in enumerate(sub_history.tolist()):
-        by_hist.setdefault(int(h), []).append(s)
-    out: List[dict] = []
-    for h in range(n_histories):
-        subs = by_hist.get(h, [])
-        rs = [results[s] for s in subs]
-        vals = [r.get("valid") for r in rs]
-        merged = merge_valid(vals) if rs else True
-        if merged is True:
-            r: dict = {"valid": True}
-            provs = {x.get("provenance") for x in rs
-                     if x.get("provenance") not in (None, "device")}
-            if provs:
-                r["provenance"] = sorted(provs)[0]
-            if any(x.get("resumed") for x in rs):
-                r["resumed"] = True
-            out.append(r)
-            continue
-        bad_subs = [s for s in subs
-                    if results[s].get("valid") is False]
-        if not bad_subs:                   # only "unknown" subs
-            r = dict(rs[vals.index(merged)])
-            r["valid"] = merged
-            out.append(r)
-            continue
+    with telemetry.span("recombine", rows=n_histories):
+        from ..checkers.core import merge_valid
+        by_hist: Dict[int, List[int]] = {}
+        for s, h in enumerate(sub_history.tolist()):
+            by_hist.setdefault(int(h), []).append(s)
+        out: List[dict] = []
+        for h in range(n_histories):
+            subs = by_hist.get(h, [])
+            rs = [results[s] for s in subs]
+            vals = [r.get("valid") for r in rs]
+            merged = merge_valid(vals) if rs else True
+            if merged is True:
+                r: dict = {"valid": True}
+                provs = {x.get("provenance") for x in rs
+                         if x.get("provenance") not in (None, "device")}
+                if provs:
+                    r["provenance"] = sorted(provs)[0]
+                if any(x.get("resumed") for x in rs):
+                    r["resumed"] = True
+                out.append(r)
+                continue
+            bad_subs = [s for s in subs
+                        if results[s].get("valid") is False]
+            if not bad_subs:                   # only "unknown" subs
+                r = dict(rs[vals.index(merged)])
+                r["valid"] = merged
+                out.append(r)
+                continue
 
-        def bad_index(s):
-            op = results[s].get("op") or {}
-            idx = op.get("index")
-            return idx if idx is not None else (1 << 31) - 1
+            def bad_index(s):
+                op = results[s].get("op") or {}
+                idx = op.get("index")
+                return idx if idx is not None else (1 << 31) - 1
 
-        win = min(bad_subs, key=bad_index)
-        r = dict(results[win])
-        r["valid"] = False
-        r["independent_key"] = sub_key[win]
-        r["failures"] = [sub_key[s] for s in bad_subs]
-        out.append(r)
-    return out
+            win = min(bad_subs, key=bad_index)
+            r = dict(results[win])
+            r["valid"] = False
+            r["independent_key"] = sub_key[win]
+            r["failures"] = [sub_key[s] for s in bad_subs]
+            out.append(r)
+        return out
 
 
 def merge_kv_histories(parts: Dict, relabel: bool = True) -> List[Op]:

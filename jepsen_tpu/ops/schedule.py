@@ -1218,7 +1218,7 @@ class _Run:
 class BucketScheduler:
     """The streaming scheduler. One instance per logical batch; not
     thread-safe; ``stats`` is a JSON-friendly dict filled as the run
-    streams (wall_s / overlap_ratio land when the generator finishes).
+    streams (wall_s lands when the generator finishes).
 
     ``min_device_rows``: consolidated wide buckets (W >= DATA_MAX_SLOTS)
     still smaller than this are yielded with the DIVERTED sentinel
@@ -1349,8 +1349,6 @@ class BucketScheduler:
             "rows": 0, "pad_rows": 0, "compiled_shapes": 0,
             "t_first_verdict_s": None, "t_first_dispatch_s": None,
             "wall_s": None,
-            "encode_busy_s": 0.0, "dispatch_busy_s": 0.0,
-            "device_wait_s": 0.0, "overlap_ratio": None,
             "events": 0, "orig_events": 0, "fusion_ratio": None,
             "retries": 0, "bisections": 0, "watchdog_fired": 0,
             "oom_events": 0, "corrupt_chunks": 0, "quarantined_rows": 0,
@@ -1364,7 +1362,6 @@ class BucketScheduler:
         }
         self._t0 = None
         self._first_dispatch_t = None
-        self._last_retire_t = None
 
     def _inc(self, key: str, n=1) -> None:
         _stat_inc(self, "wgl", key, n)
@@ -1627,7 +1624,6 @@ class BucketScheduler:
         payload instead of raised, so the pipeline keeps streaming and
         the degradation ladder (_recover) runs per member when the
         group's turn to decode comes."""
-        t0 = time.monotonic()
         outs: object
         try:
             if len(members) == 1:
@@ -1752,7 +1748,6 @@ class BucketScheduler:
         self._inc("chunks", len(members))
         for _, lo, hi, Bp in members:
             self._inc("pad_rows", Bp - (hi - lo))
-        self._inc("dispatch_busy_s", time.monotonic() - t0)
         return (members, outs, delay)
 
     # ------------------------------------------------ watchdog + ladder
@@ -1781,7 +1776,9 @@ class BucketScheduler:
         share): fire the decode-stage fault, slice off pad rows, apply
         a corrupt fault, validate (corrupt output becomes a retryable
         fault, never a wrong verdict), and shape the frontier per
-        return_frontier."""
+        return_frontier. Each read-back blocks on the device and has
+        its own ``decode.fetch`` span, so the self time of ``decode`` is
+        host work only."""
         with telemetry.span("decode", V=batch.V, W=batch.W, rows=nb):
             kind = None
             if self.faults is not None:
@@ -1790,19 +1787,22 @@ class BucketScheduler:
                 if s:
                     time.sleep(s)
             valid, bad, front = out
-            v = np.asarray(valid)[:nb]
-            b = np.asarray(bad)[:nb]
+            with telemetry.span("decode.fetch", rows=nb):
+                v = np.asarray(valid)[:nb]
+                b = np.asarray(bad)[:nb]
             if kind == "corrupt":
                 v, b = corrupt_arrays(v, b)
             validate_decoded(v, b, batch.n_events)
             fr = None
             if self.return_frontier is True:
-                fr = np.asarray(front)[:nb]
+                with telemetry.span("decode.fetch", rows=nb):
+                    fr = np.asarray(front)[:nb]
             elif self.return_frontier == "invalid":
                 fr = {}
                 rows = np.nonzero(~v)[0]
                 if rows.size:
-                    sel = np.asarray(front[rows])      # device gather
+                    with telemetry.span("decode.fetch", rows=rows.size):
+                        sel = np.asarray(front[rows])   # device gather
                     for i, r in enumerate(rows):
                         fr[int(r)] = sel[i]
             return v, b, fr
@@ -2068,7 +2068,6 @@ class BucketScheduler:
 
     def _retire(self, item) -> None:
         members, outs, delay = item
-        t0 = time.monotonic()
         if isinstance(outs, BaseException):
             # The dispatch itself already failed: there is no device
             # work to wait on, so no device-category span — a phantom
@@ -2108,12 +2107,9 @@ class BucketScheduler:
             # per-chunk, unchanged by fusion.
             results = [self._recover(run.batch, lo, hi, Bp, cause)
                        for run, lo, hi, Bp in members]
-        wait = time.monotonic() - t0
-        self._inc("device_wait_s", wait)
-        self._last_retire_t = time.monotonic()
         if self.stats["t_first_verdict_s"] is None:
             self.stats["t_first_verdict_s"] = round(
-                self._last_retire_t - self._t0, 4)
+                time.monotonic() - self._t0, 4)
         for (run, lo, hi, _), (v, b, fr) in zip(members, results):
             if self.on_chunk is not None:
                 self.on_chunk(run.batch, lo, hi, v, b, fr)
@@ -2303,7 +2299,6 @@ class BucketScheduler:
                 yield from drain()
                 out = self._run_wide(mb)
                 if not isinstance(out, WindowOverflow):
-                    self._last_retire_t = time.monotonic()
                     if self.stats["t_first_verdict_s"] is None:
                         self.stats["t_first_verdict_s"] = round(
                             time.monotonic() - self._t0, 4)
@@ -2321,7 +2316,6 @@ class BucketScheduler:
                 yield from drain()
                 out = self._run_event_routed(mb)
                 if out is not None:
-                    self._last_retire_t = time.monotonic()
                     if self.stats["t_first_verdict_s"] is None:
                         self.stats["t_first_verdict_s"] = round(
                             time.monotonic() - self._t0, 4)
@@ -2376,40 +2370,38 @@ class BucketScheduler:
                         or full):
                     yield from flush()
 
-        it = iter(groups)
-        while True:
-            te = time.monotonic()
-            try:
-                group = next(it)
-            except StopIteration:
-                break
-            self._inc("encode_busy_s", time.monotonic() - te)
+        for group in groups:
             group = [b for b in group if b.batch]
             self._inc("input_buckets", len(group))
-            if class_map is None and group:
-                # Freeze on the first NON-empty group: an all-failures
-                # prefix must not freeze an empty plan and silently
-                # disable consolidation for the whole run.
-                class_map = self._freeze_classes(group)
-            fresh: Dict[Tuple[int, int], List[EncodedBatch]] = {}
-            for b in group:
-                key = (b.V, self._class_of(class_map, b.V, b.W))
-                fresh.setdefault(key, []).append(b)
+            with telemetry.span("scheduler.plan", buckets=len(group)):
+                if class_map is None and group:
+                    # Freeze on the first NON-empty group: an
+                    # all-failures prefix must not freeze an empty plan
+                    # and silently disable consolidation for the whole
+                    # run.
+                    class_map = self._freeze_classes(group)
+                fresh: Dict[Tuple[int, int], List[EncodedBatch]] = {}
+                for b in group:
+                    key = (b.V, self._class_of(class_map, b.V, b.W))
+                    fresh.setdefault(key, []).append(b)
             for (V, cw), bs in sorted(fresh.items()):
                 pend = acc.setdefault((V, cw), [])
                 pend.extend(bs)
                 rows = sum(b.batch for b in pend)
                 chunk = self._class_chunk(V, cw)
                 if rows >= chunk:
-                    mb = merge_batches(pend, cw)
-                    full = (rows // chunk) * chunk
+                    with telemetry.span("scheduler.plan", rows=rows):
+                        mb = merge_batches(pend, cw)
+                        full = (rows // chunk) * chunk
+                        acc[(V, cw)] = ([_slice_rows(mb, full, rows)]
+                                        if full < rows else [])
                     yield from feed(_slice_rows(mb, 0, full))
-                    acc[(V, cw)] = ([_slice_rows(mb, full, rows)]
-                                    if full < rows else [])
         # Final flush of sub-chunk accumulations.
         for (V, cw), pend in sorted(acc.items()):
             if pend:
-                yield from feed(merge_batches(pend, cw))
+                with telemetry.span("scheduler.plan"):
+                    mb = merge_batches(pend, cw)
+                yield from feed(mb)
         yield from drain()
         assert not order, "every dispatched bucket must have retired"
 
@@ -2430,15 +2422,6 @@ class BucketScheduler:
             self.stats["classes"] = [
                 {"V": v, "W": c, "folds": sorted(ws)}
                 for (v, c), ws in sorted(seen.items())]
-        if self._first_dispatch_t is not None and \
-                self._last_retire_t is not None:
-            span = self._last_retire_t - self._first_dispatch_t
-            if span > 0:
-                # Fraction of the device-active span the host spent NOT
-                # blocked on results — device time hidden under encode/
-                # pad/decode work. 1.0 = fully pipelined, 0.0 = serial.
-                self.stats["overlap_ratio"] = round(
-                    max(0.0, 1.0 - self.stats["device_wait_s"] / span), 4)
 
 
 def _concat_pieces(pieces, return_frontier):
@@ -2637,8 +2620,9 @@ class GraphScheduler:
                         if s:
                             time.sleep(s)
                     cyc, node = out
-                    c = np.asarray(cyc)[:nb]
-                    nd = np.asarray(node)[:nb]
+                    with telemetry.span("decode.fetch", rows=nb):
+                        c = np.asarray(cyc)[:nb]
+                        nd = np.asarray(node)[:nb]
                     if kind == "corrupt":
                         c, nd = corrupt_arrays(c, nd)
                     validate_graph_decoded(c, nd, b.V)
@@ -2852,9 +2836,10 @@ def iter_columnar_groups(space, cols, *, max_slots: int = 16,
             type=cols.type[lo:hi], process=cols.process[lo:hi],
             kind=cols.kind[lo:hi], kinds=cols.kinds,
             index=cols.index[lo:hi] if cols.index is not None else None)
-        buckets, fails = encode_columnar(space, sub, max_slots=max_slots,
-                                         fuse=fuse, renumber=renumber,
-                                         fuse_registry=fuse_registry)
+        with telemetry.span("encode.walk", rows=hi - lo):
+            buckets, fails = encode_columnar(
+                space, sub, max_slots=max_slots, fuse=fuse,
+                renumber=renumber, fuse_registry=fuse_registry)
         for b in buckets:
             b.indices = [i + lo for i in b.indices]
             b.failures = []

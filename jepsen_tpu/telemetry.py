@@ -42,6 +42,17 @@ returning a shared singleton — no Span object, no record, nothing
 retained — so the instrumented hot paths cost one predicate each.
 ``JT_TRACE_RING`` sizes the flight recorder (default 65536 spans).
 
+Profiler bridge: while the tracer is on and a JAX profiler session is
+recording (``jax.profiler.start_trace``), every span also opens a
+``jax.profiler.TraceAnnotation`` named ``jt.<span name>`` where it
+begins and closes it where it ends, so program spans land in the
+profiler's ``.xplane.pb`` on the device trace's own clock, next to the
+device ops. The tracer never imports JAX itself: a process that has
+not loaded it gets no annotations (and no profiler could be recording).
+A span that ends on another thread than it began on gets none.
+``traced_counters`` gives the registry's counter deltas over the same
+stretch the tracer was on, to pair with its spans.
+
 Metric naming scheme (doc/observability.md): dotted
 ``subsystem.metric`` names plus sorted ``{label=value}`` suffixes —
 ``scheduler.retries{family=wgl}``, ``aot.hits``, ``wal.flush_ms``
@@ -80,6 +91,7 @@ from __future__ import annotations
 import bisect
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -98,7 +110,14 @@ _CONFIGURED = False
 
 # Trace epoch: all timestamps are monotonic ns relative to this, so
 # records from one process compose and export without clock skew.
-_EPOCH_NS = time.monotonic_ns()
+# A record's ``ts`` (µs) is at ``EPOCH_NS + ts * 1e3`` on
+# time.monotonic_ns().
+EPOCH_NS = time.monotonic_ns()
+
+# The registry's counters when configure() last turned the tracer on,
+# and when it last turned it off (None while still on).
+_COUNTS_ON: Optional[Dict[str, float]] = None
+_COUNTS_OFF: Optional[Dict[str, float]] = None
 
 _TLS = threading.local()
 _IDS = iter(range(1, 1 << 62)).__next__
@@ -172,8 +191,9 @@ def configure(trace=None, ring: Optional[int] = None) -> None:
     re-read $JT_TRACE. Reconfiguring swaps in a fresh ring buffer and
     closes any open sink — the test/bench seam."""
     global _ENABLED, _SINK_PATH, _SINK, _RING, _CONFIGURED, \
-        _SINK_ANCHORED
+        _SINK_ANCHORED, _COUNTS_ON, _COUNTS_OFF
     with _CONF_LOCK:
+        was = _ENABLED
         _SINK_ANCHORED = False
         if trace == "env":
             trace = os.environ.get("JT_TRACE")
@@ -198,6 +218,24 @@ def configure(trace=None, ring: Optional[int] = None) -> None:
         _RING = deque(maxlen=_ring_size() if ring is None else max(16,
                                                                    ring))
         _CONFIGURED = True
+        if _ENABLED and not was:
+            _COUNTS_ON, _COUNTS_OFF = REGISTRY.counters(), None
+        elif was and not _ENABLED:
+            _COUNTS_OFF = REGISTRY.counters()
+    _drop_orphans()
+
+
+def traced_counters() -> Dict[str, float]:
+    """The registry's counter deltas over the last stretch the tracer
+    was on: from when ``configure`` turned it on to when it turned it
+    off, or to now while it is still on; zero deltas dropped, {} if it
+    never was on. A traced window reads these beside its spans."""
+    base, end = _COUNTS_ON, _COUNTS_OFF
+    if base is None:
+        return {}
+    now = end if end is not None else REGISTRY.counters()
+    return {k: v - base.get(k, 0) for k, v in now.items()
+            if v - base.get(k, 0)}
 
 
 def _ensure_config() -> None:
@@ -232,7 +270,7 @@ def _emit(rec: dict) -> None:
                 # ts, which is its span's start): both are sampled at
                 # the same emit instant, so the pair is skew-free.
                 rec = {**rec, "wall_s": round(time.time(), 6),
-                       "wall_ts": (time.monotonic_ns() - _EPOCH_NS)
+                       "wall_ts": (time.monotonic_ns() - EPOCH_NS)
                        / 1e3,
                        "pid": os.getpid()}
                 _SINK_ANCHORED = True
@@ -254,6 +292,48 @@ def flush() -> None:
             _SINK = None
 
 
+# ------------------------------------------------ profiler annotations
+
+#: The span attributes an annotation carries: the cheap integers.
+_ANN_ATTRS = ("W", "rows", "chunk")
+_ANN_LABELS: Dict[str, str] = {}       # span name -> "jt.<name>"
+_TRACE_ME = None             # jax.profiler.TraceAnnotation, once loaded
+# Annotations whose span ended on another thread: never closed, kept
+# until no profiler session records, as dropping one closes it.
+_ORPHANS: list = []
+
+
+def _annotation(name: str, attrs: Optional[dict]):
+    """The profiler annotation ``jt.<name>``, opened now, or None when
+    JAX is not loaded in this process or no profiler session records."""
+    global _TRACE_ME
+    ann = _TRACE_ME
+    if ann is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:         # JAX still importing
+            return None
+        ann = _TRACE_ME = TraceAnnotation
+    if not ann.is_enabled():
+        _ORPHANS.clear()
+        return None
+    label = _ANN_LABELS.get(name)
+    if label is None:
+        label = _ANN_LABELS[name] = "jt." + name
+    if not attrs:
+        return ann(label)
+    return ann(label, **{k: attrs[k] for k in _ANN_ATTRS if k in attrs})
+
+
+def _drop_orphans() -> None:
+    """Drop the orphaned annotations unless a profiler session records:
+    then dropping one would record it."""
+    if _TRACE_ME is None or not _TRACE_ME.is_enabled():
+        _ORPHANS.clear()
+
+
 # --------------------------------------------------------------- spans
 
 class Span:
@@ -262,7 +342,7 @@ class Span:
     ride ``set(**attrs)`` (e.g. a count only known at the end)."""
 
     __slots__ = ("name", "cat", "t0", "attrs", "sid", "parent", "corr",
-                 "_done")
+                 "_done", "_ann", "_tid")
 
     def __init__(self, name: str, cat: str, attrs: Optional[dict],
                  parent: Optional[int]):
@@ -276,6 +356,9 @@ class Span:
         # correlation_scope already popped.
         self.corr = correlation()
         self._done = False
+        self._ann = _annotation(name, attrs)
+        self._tid = threading.get_ident() if self._ann is not None \
+            else None
 
     def set(self, **attrs) -> "Span":
         if self.attrs is None:
@@ -289,12 +372,19 @@ class Span:
             return
         self._done = True
         t1 = time.monotonic_ns()
+        t = threading.current_thread()
+        if self._ann is not None:
+            if t.ident == self._tid:
+                self._ann.__exit__(None, None, None)
+            else:
+                # Closing it here would record it on this thread.
+                _ORPHANS.append(self._ann)
+            self._ann = None
         stack = getattr(_TLS, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
-        t = threading.current_thread()
         rec = {"ph": "X", "name": self.name, "cat": self.cat,
-               "ts": (self.t0 - _EPOCH_NS) / 1e3,
+               "ts": (self.t0 - EPOCH_NS) / 1e3,
                "dur": (t1 - self.t0) / 1e3,
                "tid": t.ident, "tname": t.name,
                "id": self.sid}
@@ -367,7 +457,7 @@ def event(name: str, /, cat: str = "event", **attrs) -> None:
         return
     t = threading.current_thread()
     rec = {"ph": "i", "name": name, "cat": cat,
-           "ts": (time.monotonic_ns() - _EPOCH_NS) / 1e3,
+           "ts": (time.monotonic_ns() - EPOCH_NS) / 1e3,
            "tid": t.ident, "tname": t.name}
     corr = correlation()
     if corr is not None:
@@ -814,6 +904,11 @@ class Registry:
 
     def counter(self, name: str, **labels) -> _Counter:
         return _Counter(self, _key(name, labels))
+
+    def counters(self) -> Dict[str, float]:
+        """A copy of every counter's current value, by key."""
+        with self._lock:
+            return dict(self._counters)
 
     def gauge(self, name: str, **labels) -> _Gauge:
         return _Gauge(self, _key(name, labels))

@@ -5,7 +5,9 @@ nesting/attributes, ring-buffer wraparound, Chrome-trace export
 validity, registry snapshot determinism, the zero-allocation no-op when
 JT_TRACE is unset, the traced-overhead budget, end-to-end span coverage
 of the checked path (encode → dispatch → decode → journal per chunk),
-the thread-safe scheduler stats the registry replaced, the results.json
+the Op-list path's stage spans and provenance counter, the ``jt.*``
+profiler annotations (and none with tracing off), the thread-safe
+scheduler stats the registry replaced, the results.json
 ``telemetry`` block with its source tag, and the web ``/live`` view.
 """
 import json
@@ -315,6 +317,207 @@ def test_graph_path_span_coverage(traced):
             if r["name"] == "dispatch"
             and r.get("args", {}).get("family") == "graph"]
     assert disp and all(r["cat"] == "device" for r in disp)
+
+
+def _kv_batch():
+    """Four 3-key KV histories; one key of the first is corrupted."""
+    from jepsen_tpu.ops.partition import merge_kv_histories
+    from jepsen_tpu.workloads.synth import synth_cas_history
+    return [merge_kv_histories(
+        {k: synth_cas_history(300 + 10 * i + k, n_procs=3, n_ops=8,
+                              corrupt=1.0 if (i, k) == (0, 0) else 0.0)
+         for k in range(3)}) for i in range(4)]
+
+
+def test_oplist_path_stage_spans_and_provenance_counter(traced):
+    """The Op-list checked path emits one span per stage, from partition
+    to recombine, none inside an ``encode`` span, and counts every
+    sub-history under its provenance."""
+    from jepsen_tpu.models.core import cas_register
+    from jepsen_tpu.ops.linearize import check_batch_columnar
+
+    rs = check_batch_columnar(cas_register(), _kv_batch(),
+                              details="invalid")
+    assert rs[0]["valid"] is False
+    assert all(r["valid"] is True for r in rs[1:])
+    recs = [r for r in telemetry.spans() if r["ph"] == "X"]
+    names = {r["name"] for r in recs}
+    assert {"partition.histories", "columnar.convert", "encode.walk",
+            "scheduler.plan", "decode.details", "host.tail",
+            "recombine"} <= names
+    enc = {r["id"] for r in recs if r["name"] == "encode"}
+    assert enc and not any(r.get("parent") in enc for r in recs)
+    subs = {k: v for k, v in telemetry.traced_counters().items()
+            if k.startswith("check.subs{")}
+    assert sum(subs.values()) == 12            # 4 histories x 3 keys
+    assert subs.get("check.subs{provenance=device}", 0) >= 9
+
+
+def test_decode_self_time_leaves_out_the_device_wait(traced):
+    """The read-back, which blocks on the device, is ``decode.fetch``,
+    a child of ``decode``: the self time of ``decode`` is host work."""
+    from types import SimpleNamespace
+
+    from jepsen_tpu.ops.linearize import INT32_MAX
+    from jepsen_tpu.ops.schedule import BucketScheduler
+
+    class Pending:
+        """A device output still computing: reading it, or waiting for
+        it, takes 0.2 s the first time."""
+        def __init__(self, a):
+            self.a, self.ready = a, False
+
+        def block_until_ready(self):
+            if not self.ready:
+                time.sleep(0.2)
+                self.ready = True
+            return self
+
+        def __array__(self, dtype=None, copy=None):
+            return self.block_until_ready().a
+
+    out = (Pending(np.ones(4, bool)),
+           Pending(np.full(4, INT32_MAX, np.int32)), None)
+    v, b, fr = BucketScheduler()._decode_member(
+        out, 3, SimpleNamespace(V=2, W=3, n_events=8))
+    assert v.tolist() == [True] * 3 and fr is None
+    recs = telemetry.spans()
+    dec, = [r for r in recs if r["name"] == "decode"]
+    fetch, = [r for r in recs if r["name"] == "decode.fetch"]
+    assert fetch["parent"] == dec["id"] and fetch["dur"] >= 200_000  # µs
+    assert dec["dur"] - fetch["dur"] < 200_000
+
+
+def test_traced_counters_cover_the_traced_stretch():
+    c = telemetry.REGISTRY.counter("test.stretch")
+    telemetry.configure(False)
+    try:
+        c.inc(5)
+        telemetry.configure(True)
+        c.inc(2)
+        assert telemetry.traced_counters()["test.stretch"] == 2
+        telemetry.configure(False)
+        c.inc(7)
+        assert telemetry.traced_counters()["test.stretch"] == 2
+    finally:
+        telemetry.configure("env")
+
+
+# ------------------------------------------------ profiler bridge
+
+def _profiled(out_dir, body):
+    """Run ``body`` under a CPU profiler session; the ``jt.*`` host
+    events it recorded as (span name, start ns, end ns, stats), by
+    start."""
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(out_dir), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    f, = out_dir.glob("**/*.xplane.pb")
+    pd = ProfileData.from_file(str(f))
+    return sorted(((ev.name[3:], ev.start_ns, ev.start_ns + ev.duration_ns,
+                    dict(ev.stats))
+                   for pl in pd.planes for ln in pl.lines
+                   for ev in ln.events if ev.name.startswith("jt.")),
+                  key=lambda e: e[1])
+
+
+def test_spans_reach_the_profiler_trace(traced, tmp_path):
+    """Under a profiler session every span is a ``jt.<name>`` host event
+    with its cheap integer attributes, nested as the spans are,
+    including a ``begin`` span held across a generator's yields."""
+    def drive():
+        sp = telemetry.begin("drive", W=7)
+        try:
+            for i in range(3):
+                with telemetry.span("step", chunk=i, tag="x"):
+                    time.sleep(0.001)
+                yield i
+        finally:
+            sp.end()
+
+    def body():
+        with telemetry.span("outer", rows=4):
+            with telemetry.span("inner"):
+                time.sleep(0.001)
+        for _ in drive():
+            with telemetry.span("between"):
+                time.sleep(0.001)
+
+    evs = _profiled(tmp_path, body)
+    by = {}
+    for name, s, e, stats in evs:
+        by.setdefault(name, []).append((s, e, stats))
+    assert sorted(by) == ["between", "drive", "inner", "outer", "step"]
+    (o0, o1, ostats), = by["outer"]
+    (i0, i1, _), = by["inner"]
+    assert o0 <= i0 and i1 <= o1 and ostats == {"rows": 4}
+    (d0, d1, dstats), = by["drive"]
+    assert dstats == {"W": 7}
+    assert [st for *_, st in by["step"]] == [{"chunk": i} for i in range(3)]
+    assert len(by["between"]) == 3
+    assert all(d0 <= s and e <= d1 for s, e, _ in by["step"] + by["between"])
+    # The flight recorder holds the same spans.
+    assert sorted(r["name"] for r in telemetry.spans()) == sorted(
+        name for name, *_ in evs)
+
+
+def test_span_ended_on_another_thread_gets_no_annotation(traced,
+                                                         tmp_path):
+    def body():
+        box = []
+        t = threading.Thread(
+            target=lambda: box.append(telemetry.begin("handoff")))
+        t.start()
+        t.join()
+        box[0].end()
+        with telemetry.span("local"):
+            pass
+
+    evs = _profiled(tmp_path, body)
+    assert [name for name, *_ in evs] == ["local"]
+    assert {r["name"] for r in telemetry.spans()} == {"handoff", "local"}
+
+
+def test_no_annotation_is_built_with_tracing_off(tmp_path, monkeypatch):
+    """Tracing off, the checked path's span sites build no annotation
+    even under a profiler session: ``span``/``begin`` return the no-op
+    after the one ``enabled`` predicate."""
+    import jax
+    from jepsen_tpu.models.core import cas_register
+    from jepsen_tpu.ops.linearize import check_batch_columnar
+
+    built = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(telemetry, "_TRACE_ME", Counting)
+    hists = _kv_batch()
+    telemetry.configure(False)
+    try:
+        def off():
+            assert telemetry.span("x") is telemetry.NOP
+            assert telemetry.begin("y") is telemetry.NOP
+            check_batch_columnar(cas_register(), hists, details="invalid")
+
+        assert _profiled(tmp_path / "off", off) == []
+        assert built == []
+        telemetry.configure(True)
+        evs = _profiled(tmp_path / "on", lambda: check_batch_columnar(
+            cas_register(), hists, details="invalid"))
+        assert built and len(built) == len(evs)
+        assert "jt.partition.histories" in built
+    finally:
+        telemetry.configure("env")
 
 
 # ------------------------------------------- results.json integration
