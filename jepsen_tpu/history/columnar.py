@@ -64,6 +64,11 @@ class ColumnarOps:
               P-compositional pre-partition (ops.partition) strains a
               keyed batch into per-key sub-histories before encoding,
               and the sub-batches it produces carry no key column.
+              Batches converted from KV-valued Op lists
+              (``ops_to_columnar(keyed=True)``) carry row-local ids:
+              the key's rank in its history's first-seen key order.
+    key_labels — optional per-row lists of key labels by row-local id,
+              on batches converted with ``keyed=True``.
     meta    — optional generator-side metadata
               (ops.synth_device.SynthMeta): per-history (and per-key)
               peak pending windows computed as part of generation, so
@@ -80,6 +85,7 @@ class ColumnarOps:
     index: Optional[np.ndarray] = None
     key: Optional[np.ndarray] = None
     meta: Optional[object] = None
+    key_labels: Optional[List[list]] = None
 
     @property
     def batch(self) -> int:
@@ -103,12 +109,31 @@ def _kind_value(kind: Tuple):
 
 
 def _walk_py(histories: Sequence[Sequence[Op]], vocab: dict,
-             all_kinds: List[Tuple]):
+             all_kinds: List[Tuple], kv=None):
     """Pure-Python twin of the native ingest walk (native/ingest.cpp):
     pairing, failure retraction, and value propagation over recorded
     histories, emitting flat line buffers. The oracle for the native
-    walk's parity tests and the fallback when it can't build."""
+    walk's parity tests and the fallback when it can't build.
+
+    With ``kv`` (the independent.KV type) the walk is keyed, as the
+    native walk's keyed mode: KV values are unwrapped, each line carries
+    its key's rank in the history's first-seen key order, and the
+    result adds the lines' key ranks and each history's keys by rank
+    (see ``ops_to_columnar``); None where an op would be placed
+    otherwise than by the per-key walk of its sub-history."""
     from ..ops.statespace import canonical_value
+
+    def value(op):
+        v = op.value
+        return v.value if kv is not None and isinstance(v, kv) else v
+
+    def intern(f, v):
+        k = (f, canonical_value(v))
+        ki = vocab.get(k)
+        if ki is None:
+            ki = vocab[k] = len(all_kinds)
+            all_kinds.append(k)
+        return ki
 
     code: List[int] = []
     proc: List[int] = []
@@ -117,41 +142,48 @@ def _walk_py(histories: Sequence[Sequence[Op]], vocab: dict,
     okflag: List[int] = []
     link: List[int] = []
     rowlen: List[int] = []
+    key: List[int] = []
+    labels: List[list] = []
     for h in histories:
         rowstart = len(code)
-        open_line: dict = {}     # process -> flat invoke-line index
-        open_fv: dict = {}       # process -> (f, value)
+        opened: dict = {}        # process -> (invoke line, op, key)
         dense: dict = {}         # process -> per-row dense id
+        ranks: dict = {}         # key -> rank, first seen first
         for pos, op in enumerate(h):
+            okey = -1
+            if kv is not None and isinstance(op.value, kv):
+                okey = ranks.setdefault(op.value.key, len(ranks))
             p = op.process
             if not isinstance(p, int):
                 continue
             t = op.type
+            ix = op.index if op.index is not None else pos
+            o = opened.get(p)
             if t == "invoke":
-                open_line[p] = len(code)
-                open_fv[p] = (op.f, op.value)
+                if kv is not None and (op.index is None or (
+                        o is not None and o[2] != okey)):
+                    return None
+                opened[p] = (len(code), op, okey)
+                lkey = okey
                 code.append(C_INVOKE)
                 proc.append(dense.setdefault(p, len(dense)))
-                kind.append(-1)
-                oidx.append(op.index if op.index is not None else pos)
-                okflag.append(0)
+                oidx.append(ix)
                 link.append(-1)
             elif t == "ok" or t == "info":
-                j = open_line.pop(p, None)
-                if j is None:
+                if o is None:
                     continue
-                f, v = open_fv.pop(p)
+                if kv is not None and (op.index is None or (
+                        okey >= 0 and okey != o[2])):
+                    return None
+                del opened[p]
+                j, inv, lkey = o
+                v = value(inv)
                 if v is None and t == "ok":
                     # Only ok completions propagate observations
                     # (history.core.complete semantics): an info op's
                     # value is not an observation.
-                    v = op.value
-                k = (f, canonical_value(v))
-                ki = vocab.get(k)
-                if ki is None:
-                    ki = vocab[k] = len(all_kinds)
-                    all_kinds.append(k)
-                kind[j] = ki
+                    v = value(op)
+                kind[j] = intern(inv.f, v)
                 if t == "ok":
                     okflag[j] = 1
                     code.append(C_OK)
@@ -160,35 +192,71 @@ def _walk_py(histories: Sequence[Sequence[Op]], vocab: dict,
                     code.append(C_INFO)
                     link.append(j)
                 proc.append(proc[j])
-                kind.append(-1)
-                oidx.append(op.index if op.index is not None else pos)
-                okflag.append(0)
-            elif t == "fail":
+                oidx.append(ix)
+            elif t == "fail" and o is not None:
                 # Definitely didn't happen: retract the invoke line.
-                j = open_line.pop(p, None)
-                open_fv.pop(p, None)
-                if j is not None:
-                    code[j] = PAD
+                if kv is not None and okey >= 0 and okey != o[2]:
+                    return None
+                del opened[p]
+                code[o[0]] = PAD
+                continue
+            else:
+                continue
+            kind.append(-1)
+            okflag.append(0)
+            if kv is not None:
+                key.append(lkey)
         # Crashed invocations (no completion): kind from the invoke.
-        for p, j in open_line.items():
-            f, v = open_fv[p]
-            k = (f, canonical_value(v))
-            ki = vocab.get(k)
-            if ki is None:
-                ki = vocab[k] = len(all_kinds)
-                all_kinds.append(k)
-            kind[j] = ki
+        for j, inv, _ in sorted(opened.values(), key=lambda x: x[0]):
+            kind[j] = intern(inv.f, value(inv))
+        if kv is not None:
+            labels.append(list(ranks))
+            _key_row((code, proc, kind, oidx, okflag, link, key),
+                     rowstart, len(ranks))
         rowlen.append(len(code) - rowstart)
-    return (np.asarray(code, np.int8), np.asarray(proc, np.int32),
-            np.asarray(kind, np.int32), np.asarray(oidx, np.int32),
-            np.asarray(okflag, np.int8), np.asarray(link, np.int32),
-            np.asarray(rowlen, np.int64))
+    out = (np.asarray(code, np.int8), np.asarray(proc, np.int32),
+           np.asarray(kind, np.int32), np.asarray(oidx, np.int32),
+           np.asarray(okflag, np.int8), np.asarray(link, np.int32),
+           np.asarray(rowlen, np.int64))
+    if kv is None:
+        return out
+    return out + (np.asarray(key, np.int32), labels)
+
+
+def _key_row(lines, start: int, n_keys: int) -> None:
+    """A keyed row once walked (``_walk_py``'s twin of the native
+    ``key_row``): each unkeyed line (key -1) of a row with keys is
+    copied once per key, the copies adjacent, as subhistory puts it in
+    every key's sub-history; then process ids are made dense per key in
+    line order, retracted invokes included, as the per-key walk's
+    are."""
+    if not n_keys:
+        return
+    code, proc, kind, oidx, okflag, link, key = lines
+    if -1 in key[start:]:
+        old = [col[start:] for col in lines]
+        for col in lines:
+            del col[start:]
+        at = []                            # first copy of each old line
+        for c, p, ki, ix, ok, ln, k in zip(*old):
+            at.append(len(code))
+            for copy in (range(n_keys) if k < 0 else (k,)):
+                for col, x in zip(lines, (c, p, ki, ix, ok)):
+                    col.append(x)
+                link.append(-1 if ln < 0 else
+                            at[ln - start] + (copy if k < 0 else 0))
+                key.append(copy)
+    per_key: List[dict] = [{} for _ in range(n_keys)]
+    for j in range(start, len(code)):
+        d = per_key[key[j]]
+        proc[j] = d.setdefault(proc[j], len(d))
 
 
 def _pack_walk(model, bufs_or_arrays, all_kinds: List[Tuple],
-               max_states: int) -> ColumnarOps:
+               max_states: int, key=None) -> ColumnarOps:
     """Shared post-pass over a walk's flat buffers: identity-drop and
-    padding into a ColumnarOps (the second half of ops_to_columnar)."""
+    padding into a ColumnarOps (the second half of ops_to_columnar).
+    ``key``, a keyed walk's per-line key ranks, lands in ``.key``."""
     from ..ops.statespace import enumerate_statespace
 
     code, proc, kind, oidx, okflag, link, rowlen = bufs_or_arrays
@@ -222,20 +290,28 @@ def _pack_walk(model, bufs_or_arrays, all_kinds: List[Tuple],
     procs[rid, posin] = proc[keep].astype(np.int16)
     kinds_arr[rid, posin] = kind[keep]
     index[rid, posin] = oidx[keep]
+    keys = None
+    if key is not None:
+        keys = np.full((B, max(N, 1)), -1, np.int32)
+        keys[rid, posin] = key[keep]
     return ColumnarOps(type=typ, process=procs, kind=kinds_arr,
-                       kinds=all_kinds, index=index)
+                       kinds=all_kinds, index=index, key=keys)
 
 
 def _from_bufs(bufs):
     """Native walk byte buffers -> typed arrays (Py_BuildValue("y#")
-    yields None for an empty vector's nullptr)."""
-    return (np.frombuffer(bufs[0] or b"", np.int8),
-            np.frombuffer(bufs[1] or b"", np.int32),
-            np.frombuffer(bufs[2] or b"", np.int32).copy(),
-            np.frombuffer(bufs[3] or b"", np.int32),
-            np.frombuffer(bufs[4] or b"", np.int8),
-            np.frombuffer(bufs[5] or b"", np.int32),
-            np.frombuffer(bufs[6] or b"", np.int64))
+    yields None for an empty vector's nullptr); a keyed walk's key
+    ranks and key labels follow."""
+    out = (np.frombuffer(bufs[0] or b"", np.int8),
+           np.frombuffer(bufs[1] or b"", np.int32),
+           np.frombuffer(bufs[2] or b"", np.int32).copy(),
+           np.frombuffer(bufs[3] or b"", np.int32),
+           np.frombuffer(bufs[4] or b"", np.int8),
+           np.frombuffer(bufs[5] or b"", np.int32),
+           np.frombuffer(bufs[6] or b"", np.int64))
+    if len(bufs) > 7:
+        out += (np.frombuffer(bufs[7] or b"", np.int32), bufs[8])
+    return out
 
 
 def _seed_vocab(kinds: Optional[List[Tuple]]):
@@ -291,7 +367,8 @@ def jsonl_to_columnar(model, texts: Sequence, *,
 def ops_to_columnar(model, histories: Sequence[Sequence[Op]], *,
                     kinds: Optional[List[Tuple]] = None,
                     max_states: int = 64,
-                    native: bool = True) -> ColumnarOps:
+                    native: bool = True,
+                    keyed: bool = False) -> Optional[ColumnarOps]:
     """Convert recorded/stored Op-list histories into one prepared
     ColumnarOps — the ingest ramp onto the columnar fast path for
     histories the framework actually executed or reloaded
@@ -322,9 +399,25 @@ def ops_to_columnar(model, histories: Sequence[Sequence[Op]], *,
     (native/ingest.cpp) when available (``native=False`` forces the
     pure-Python twin); the identity-drop + padding pass is vectorized
     numpy either way.
+
+    ``keyed=True`` converts KV-valued histories (jepsen_tpu.independent)
+    for the per-key strain (ops.partition.strain_keyed): values are
+    unwrapped, ``.key`` holds each line's key rank in its history's
+    first-seen key order (-1 on a history without keys) and
+    ``.key_labels`` the keys by rank, and process ids are dense per
+    (history, key); an unkeyed client op of a history with keys is
+    copied into every key. The per-key lines are then exactly those
+    ``independent.subhistory`` followed by the unkeyed conversion gives.
+    Returns None where that cannot hold and the caller must strain Op
+    lists instead: a completion naming another key than its
+    invocation's, an invocation while the process has one open under
+    another key, or an op line without an index.
     """
     with telemetry.span("columnar.convert"):
         vocab, all_kinds = _seed_vocab(kinds)
+        kv = None
+        if keyed:
+            from ..independent import KV as kv
         ext = None
         if native:
             from ..native import ingest
@@ -332,10 +425,19 @@ def ops_to_columnar(model, histories: Sequence[Sequence[Op]], *,
         if ext is not None:
             histories = [h if isinstance(h, (list, tuple)) else list(h)
                          for h in histories]
-            arrays = _from_bufs(ext.walk(histories, vocab, all_kinds))
+            out = ext.walk(histories, vocab, all_kinds, kv)
+            if out is not None:
+                out = _from_bufs(out)
         else:
-            arrays = _walk_py(histories, vocab, all_kinds)
-        return _pack_walk(model, arrays, all_kinds, max_states)
+            out = _walk_py(histories, vocab, all_kinds, kv)
+        if out is None:
+            return None
+        if not keyed:
+            return _pack_walk(model, out, all_kinds, max_states)
+        cols = _pack_walk(model, out[:7], all_kinds, max_states,
+                          key=out[7])
+        cols.key_labels = out[8]
+        return cols
 
 
 def columnar_to_ops(cols: ColumnarOps, row: int,

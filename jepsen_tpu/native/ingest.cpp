@@ -19,6 +19,15 @@
 //     completion's observed value when the invoke recorded None;
 //   * kinds are interned into the caller's vocab dict / kinds list so
 //     indices stay aligned across walks and with seeded vocabularies.
+//
+// Keyed mode (walk's fourth argument, the independent.KV type) takes
+// the per-key strain of KV-valued histories into the same pass: values
+// are unwrapped before kind interning, every line carries its key's
+// rank in the history's first-seen key order (independent.history_keys),
+// and process ids are dense per (history, key) -- the lines that
+// ops.partition.partition_columnar then gathers per key are exactly
+// independent.subhistory's, converted. Any op whose placement would
+// differ from the per-key walk makes the whole batch bail (None).
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
 
@@ -86,21 +95,35 @@ PyObject* canon(PyObject* v) {
   return v;
 }
 
+// An op's value (new reference), its independent.KV wrapper taken off
+// when kv_type is set (keyed mode).
+PyObject* op_value(PyObject* op, PyTypeObject* kv_type) {
+  PyObject* v = PyObject_GetAttr(op, s_value);
+  if (v && kv_type && PyObject_TypeCheck(v, kv_type) &&
+      PyTuple_GET_SIZE(v) == 2) {
+    PyObject* inner = PyTuple_GET_ITEM(v, 1);
+    Py_INCREF(inner);
+    Py_DECREF(v);
+    return inner;
+  }
+  return v;
+}
+
 // Intern (f, canon(value)) into vocab/kinds; returns kind index or -2 on
-// error. `value_fallback` supplies the observed value when the invoke
+// error. `completion` supplies the observed value when the invoke
 // recorded None.
 int32_t intern_kind(PyObject* vocab, PyObject* kinds, PyObject* inv,
-                    PyObject* completion) {
+                    PyObject* completion, PyTypeObject* kv_type) {
   PyObject* f = PyObject_GetAttr(inv, s_f);
   if (!f) return -2;
-  PyObject* v = PyObject_GetAttr(inv, s_value);
+  PyObject* v = op_value(inv, kv_type);
   if (!v) {
     Py_DECREF(f);
     return -2;
   }
   if (v == Py_None && completion) {
     Py_DECREF(v);
-    v = PyObject_GetAttr(completion, s_value);
+    v = op_value(completion, kv_type);
     if (!v) {
       Py_DECREF(f);
       return -2;
@@ -145,159 +168,280 @@ int32_t op_index_or(PyObject* op, int32_t dflt) {
     PyErr_Clear();
     return dflt;
   }
-  int32_t r = (pi == Py_None) ? dflt : (int32_t)PyLong_AsLong(pi);
+  long r = (pi == Py_None) ? dflt : PyLong_AsLong(pi);
   Py_DECREF(pi);
+  if (r == -1 && PyErr_Occurred()) {  // not an int: as if absent
+    PyErr_Clear();
+    return dflt;
+  }
+  return (int32_t)r;
+}
+
+// 0 invoke, 1 ok, 2 fail, 3 info, -1 any other type, -2 on error.
+int op_type(PyObject* op) {
+  PyObject* pt = PyObject_GetAttr(op, s_type);
+  if (!pt) return -2;
+  // Frequency order: invoke, ok, fail, info. Compare by content —
+  // ops loaded from jsonl carry non-interned type strings.
+  int t;
+  if (PyUnicode_CompareWithASCIIString(pt, "invoke") == 0)
+    t = 0;
+  else if (PyUnicode_CompareWithASCIIString(pt, "ok") == 0)
+    t = 1;
+  else if (PyUnicode_CompareWithASCIIString(pt, "fail") == 0)
+    t = 2;
+  else if (PyUnicode_CompareWithASCIIString(pt, "info") == 0)
+    t = 3;
+  else
+    t = -1;
+  Py_DECREF(pt);
+  return t;
+}
+
+// Rank of an op's KV key in its history's first-seen key order (keymap:
+// key -> rank; row_keys: the keys by rank), -1 when its value is no KV,
+// -2 on error.
+int32_t key_rank(PyObject* op, PyTypeObject* kv_type, PyObject* keymap,
+                 PyObject* row_keys) {
+  PyObject* v = PyObject_GetAttr(op, s_value);
+  if (!v) return -2;
+  int32_t r = -1;
+  if (PyObject_TypeCheck(v, kv_type) && PyTuple_GET_SIZE(v) == 2) {
+    PyObject* k = PyTuple_GET_ITEM(v, 0);
+    PyObject* ro = PyDict_GetItemWithError(keymap, k);  // borrowed
+    if (ro) {
+      r = (int32_t)PyLong_AsLong(ro);
+    } else if (PyErr_Occurred()) {
+      r = -2;
+    } else {
+      r = (int32_t)PyList_GET_SIZE(row_keys);
+      PyObject* rn = PyLong_FromLong(r);
+      if (!rn || PyDict_SetItem(keymap, k, rn) < 0 ||
+          PyList_Append(row_keys, k) < 0)
+        r = -2;
+      Py_XDECREF(rn);
+    }
+  }
+  Py_DECREF(v);
   return r;
 }
 
-// walk(histories, vocab, kinds) ->
+// The flat line buffers of a walk; `key` is filled in keyed mode only.
+struct Lines {
+  std::vector<int8_t> code, okflag;
+  std::vector<int32_t> proc, kind, oidx, link, key;
+
+  size_t size() const { return code.size(); }
+  void push(int8_t c, int32_t p, int32_t o, int32_t l, int32_t k,
+            bool keyed) {
+    code.push_back(c);
+    proc.push_back(p);
+    kind.push_back(-1);
+    oidx.push_back(o);
+    okflag.push_back(0);
+    link.push_back(l);
+    if (keyed) key.push_back(k);
+  }
+};
+
+// A keyed row once walked: each unkeyed line (key -1) of a row with keys
+// is copied once per key, the copies adjacent, as subhistory puts it in
+// every key's sub-history; then process ids are made dense per key in
+// line order, retracted invokes included, as the per-key walk's are.
+void key_row(Lines& L, size_t start, int32_t n_keys) {
+  if (n_keys == 0) return;
+  if (std::find(L.key.begin() + start, L.key.end(), -1) != L.key.end()) {
+    Lines old;
+    old.code.assign(L.code.begin() + start, L.code.end());
+    old.okflag.assign(L.okflag.begin() + start, L.okflag.end());
+    old.proc.assign(L.proc.begin() + start, L.proc.end());
+    old.kind.assign(L.kind.begin() + start, L.kind.end());
+    old.oidx.assign(L.oidx.begin() + start, L.oidx.end());
+    old.link.assign(L.link.begin() + start, L.link.end());
+    old.key.assign(L.key.begin() + start, L.key.end());
+    for (auto* v : {&L.proc, &L.kind, &L.oidx, &L.link, &L.key})
+      v->resize(start);
+    L.code.resize(start);
+    L.okflag.resize(start);
+    std::vector<int64_t> at(old.size());  // first copy of each old line
+    for (size_t j = 0; j < old.size(); j++) {
+      at[j] = (int64_t)L.size();
+      int32_t k = old.key[j];
+      for (int32_t c = 0; c < (k < 0 ? n_keys : 1); c++) {
+        int32_t ln = old.link[j];
+        L.push(old.code[j], old.proc[j], old.oidx[j],
+               ln < 0 ? -1 : (int32_t)(at[ln - start] + (k < 0 ? c : 0)),
+               k < 0 ? c : k, true);
+        L.kind.back() = old.kind[j];
+        L.okflag.back() = old.okflag[j];
+      }
+    }
+  }
+  std::unordered_map<int64_t, int32_t> dense;
+  std::vector<int32_t> next((size_t)n_keys, 0);
+  for (size_t j = start; j < L.size(); j++) {
+    int32_t k = L.key[j];
+    auto r = dense.emplace(((int64_t)k << 32) | (uint32_t)L.proc[j],
+                           next[k]);
+    if (r.second) next[k]++;
+    L.proc[j] = r.first->second;
+  }
+}
+
+enum { ROW_OK = 0, ROW_ERROR = -1, ROW_BAIL = 1 };
+
+// One history's lines appended to L. In keyed mode (kv_type set) its
+// keys land in row_keys by rank; ROW_BAIL when an op would be placed
+// otherwise than by the per-key walk of its sub-history.
+int walk_row(PyObject* ofast, PyObject* vocab, PyObject* kinds,
+             PyTypeObject* kv_type, PyObject* keymap, PyObject* row_keys,
+             Lines& L) {
+  const bool keyed = kv_type != nullptr;
+  // Open invokes by process; ops are borrowed (the list keeps them).
+  struct Open {
+    int64_t j;
+    PyObject* op;
+    int32_t key;
+  };
+  std::unordered_map<long long, Open> open;
+  std::unordered_map<long long, int32_t> dense;
+  size_t start = L.size();
+  Py_ssize_t nop = PySequence_Fast_GET_SIZE(ofast);
+
+  for (Py_ssize_t pos = 0; pos < nop; pos++) {
+    PyObject* op = PySequence_Fast_GET_ITEM(ofast, pos);
+    // Keys of every op count, client or not: the first-seen key order
+    // is independent.history_keys'.
+    int32_t okey = keyed ? key_rank(op, kv_type, keymap, row_keys) : -1;
+    if (okey == -2) return ROW_ERROR;
+    PyObject* pp = PyObject_GetAttr(op, s_process);
+    if (!pp) return ROW_ERROR;
+    if (!PyLong_Check(pp)) {
+      Py_DECREF(pp);
+      continue;
+    }
+    long long p = PyLong_AsLongLong(pp);
+    Py_DECREF(pp);
+    if (p == -1 && PyErr_Occurred()) return ROW_ERROR;
+    int t = op_type(op);
+    if (t == -2) return ROW_ERROR;
+    auto it = open.find(p);
+
+    if (t == 0) {  // invoke
+      // Keyed, a missing index bails: the per-key walk would number
+      // the line by its position in the sub-history.
+      int32_t ix = op_index_or(op, keyed ? -1 : (int32_t)pos);
+      if (keyed && (ix < 0 || (it != open.end() && it->second.key != okey)))
+        return ROW_BAIL;
+      auto r = dense.emplace(p, (int32_t)dense.size());
+      open[p] = Open{(int64_t)L.size(), op, okey};
+      L.push(LINE_INVOKE, r.first->second, ix, -1, okey, keyed);
+    } else if (t == 1 || t == 3) {  // ok / info
+      if (it == open.end()) continue;
+      Open o = it->second;
+      // A completion takes its invocation's key; one that names
+      // another key bails.
+      int32_t ix = op_index_or(op, keyed ? -1 : (int32_t)pos);
+      if (keyed && (ix < 0 || (okey >= 0 && okey != o.key)))
+        return ROW_BAIL;
+      open.erase(it);
+      // Only ok completions propagate observations onto the invoke
+      // (history.core.complete semantics).
+      int32_t ki =
+          intern_kind(vocab, kinds, o.op, t == 1 ? op : nullptr, kv_type);
+      if (ki == -2) return ROW_ERROR;
+      L.kind[o.j] = ki;
+      if (t == 1) L.okflag[o.j] = 1;
+      L.push(t == 1 ? LINE_OK : LINE_INFO, L.proc[o.j], ix,
+             t == 3 ? (int32_t)o.j : -1, o.key, keyed);
+    } else if (t == 2 && it != open.end()) {  // fail: retract the invoke
+      if (keyed && okey >= 0 && okey != it->second.key) return ROW_BAIL;
+      L.code[it->second.j] = LINE_PAD;
+      open.erase(it);
+    }
+  }
+
+  // Crashed invocations: kind from the invoke's own value, interned
+  // in invocation (line) order so the kinds vocabulary is
+  // bit-identical to the Python oracle's insertion order (same
+  // discipline as walk_jsonl).
+  std::vector<std::pair<int64_t, PyObject*>> crashed;
+  crashed.reserve(open.size());
+  for (auto& kv : open) crashed.emplace_back(kv.second.j, kv.second.op);
+  std::sort(crashed.begin(), crashed.end());
+  for (auto& c : crashed) {
+    int32_t ki = intern_kind(vocab, kinds, c.second, nullptr, kv_type);
+    if (ki == -2) return ROW_ERROR;
+    L.kind[c.first] = ki;
+  }
+  if (keyed) key_row(L, start, (int32_t)PyList_GET_SIZE(row_keys));
+  return ROW_OK;
+}
+
+#define BUF(v) \
+  (const char*)(v).data(), (Py_ssize_t)((v).size() * sizeof((v)[0]))
+
+// walk(histories, vocab, kinds[, kv_type]) ->
 //   (code, proc, kind, oidx, okflag, link, rowlen) as bytes buffers
 //   [int8, int32, int32, int32, int8, int32, int64].
+// With kv_type (the independent.KV class) the walk is keyed: the tuple
+// adds the lines' key ranks (int32) and each history's keys by rank (a
+// list of lists); the result is None when an op would be placed
+// otherwise than by the per-key walk: a completion naming another key
+// than its invocation's, an invocation while the process has one open
+// under another key, or a line whose op has no index.
 PyObject* walk(PyObject*, PyObject* args) {
-  PyObject *histories, *vocab, *kinds;
-  if (!PyArg_ParseTuple(args, "OOO", &histories, &vocab, &kinds))
+  PyObject *histories, *vocab, *kinds, *kv = Py_None;
+  if (!PyArg_ParseTuple(args, "OOO|O", &histories, &vocab, &kinds, &kv))
     return nullptr;
   if (!PyDict_Check(vocab) || !PyList_Check(kinds)) {
     PyErr_SetString(PyExc_TypeError, "vocab must be dict, kinds list");
     return nullptr;
   }
-
-  std::vector<int8_t> code;
-  std::vector<int32_t> proc, kind, oidx, link;
-  std::vector<int8_t> okflag;
-  std::vector<int64_t> rowlen;
+  if (kv != Py_None && !PyType_Check(kv)) {
+    PyErr_SetString(PyExc_TypeError, "kv_type must be a type");
+    return nullptr;
+  }
+  PyTypeObject* kv_type = kv == Py_None ? nullptr : (PyTypeObject*)kv;
 
   PyObject* hfast = PySequence_Fast(histories, "expected history list");
   if (!hfast) return nullptr;
   Py_ssize_t nh = PySequence_Fast_GET_SIZE(hfast);
+  PyObject* labels = kv_type ? PyList_New(0) : nullptr;
+  Lines L;
+  std::vector<int64_t> rowlen;
   rowlen.reserve(nh);
+  int rc = (kv_type && !labels) ? ROW_ERROR : ROW_OK;
 
-  // op objects are borrowed: the history lists keep them alive.
-  std::unordered_map<long long, int64_t> open_line;
-  std::unordered_map<long long, PyObject*> open_op;
-  std::unordered_map<long long, int32_t> dense;
-
-  for (Py_ssize_t hi = 0; hi < nh; hi++) {
-    PyObject* h = PySequence_Fast_GET_ITEM(hfast, hi);
-    PyObject* ofast = PySequence_Fast(h, "expected op list");
-    if (!ofast) {
-      Py_DECREF(hfast);
-      return nullptr;
-    }
-    Py_ssize_t nop = PySequence_Fast_GET_SIZE(ofast);
-    int64_t rowstart = (int64_t)code.size();
-    open_line.clear();
-    open_op.clear();
-    dense.clear();
-
-    for (Py_ssize_t pos = 0; pos < nop; pos++) {
-      PyObject* op = PySequence_Fast_GET_ITEM(ofast, pos);
-      PyObject* pp = PyObject_GetAttr(op, s_process);
-      if (!pp) goto fail;
-      if (!PyLong_Check(pp)) {
-        Py_DECREF(pp);
-        continue;
-      }
-      {
-        long long p = PyLong_AsLongLong(pp);
-        Py_DECREF(pp);
-        PyObject* pt = PyObject_GetAttr(op, s_type);
-        if (!pt) goto fail;
-        // Frequency order: invoke, ok, fail, info. Compare by content —
-        // ops loaded from jsonl carry non-interned type strings.
-        int t;
-        if (PyUnicode_CompareWithASCIIString(pt, "invoke") == 0)
-          t = 0;
-        else if (PyUnicode_CompareWithASCIIString(pt, "ok") == 0)
-          t = 1;
-        else if (PyUnicode_CompareWithASCIIString(pt, "fail") == 0)
-          t = 2;
-        else if (PyUnicode_CompareWithASCIIString(pt, "info") == 0)
-          t = 3;
-        else
-          t = -1;
-        Py_DECREF(pt);
-
-        if (t == 0) {  // invoke
-          int64_t j = (int64_t)code.size();
-          open_line[p] = j;
-          open_op[p] = op;
-          auto r = dense.emplace(p, (int32_t)dense.size());
-          code.push_back(LINE_INVOKE);
-          proc.push_back(r.first->second);
-          kind.push_back(-1);
-          oidx.push_back(op_index_or(op, (int32_t)pos));
-          okflag.push_back(0);
-          link.push_back(-1);
-        } else if (t == 1 || t == 3) {  // ok / info
-          auto it = open_line.find(p);
-          if (it == open_line.end()) continue;
-          int64_t j = it->second;
-          open_line.erase(it);
-          PyObject* inv = open_op[p];
-          open_op.erase(p);
-          // Only ok completions propagate observations onto the invoke
-          // (history.core.complete semantics).
-          int32_t ki = intern_kind(vocab, kinds, inv, t == 1 ? op : nullptr);
-          if (ki == -2) goto fail;
-          kind[j] = ki;
-          if (t == 1) okflag[j] = 1;
-          code.push_back(t == 1 ? LINE_OK : LINE_INFO);
-          proc.push_back(proc[j]);
-          kind.push_back(-1);
-          oidx.push_back(op_index_or(op, (int32_t)pos));
-          okflag.push_back(0);
-          link.push_back(t == 3 ? (int32_t)j : -1);
-        } else if (t == 2) {  // fail: retract the invoke line
-          auto it = open_line.find(p);
-          if (it != open_line.end()) {
-            code[it->second] = LINE_PAD;
-            open_line.erase(it);
-            open_op.erase(p);
-          }
-        }
-      }
-      continue;
-    fail:
-      Py_DECREF(ofast);
-      Py_DECREF(hfast);
-      return nullptr;
-    }
-
-    // Crashed invocations: kind from the invoke's own value, interned
-    // in invocation (line) order so the kinds vocabulary is
-    // bit-identical to the Python oracle's insertion order (same
-    // discipline as walk_jsonl).
-    std::vector<std::pair<int64_t, long>> crashed;
-    crashed.reserve(open_line.size());
-    for (auto& kv : open_line)
-      crashed.emplace_back(kv.second, kv.first);
-    std::sort(crashed.begin(), crashed.end());
-    for (auto& pr : crashed) {
-      int32_t ki = intern_kind(vocab, kinds, open_op[pr.second],
-                               nullptr);
-      if (ki == -2) {
-        Py_DECREF(ofast);
-        Py_DECREF(hfast);
-        return nullptr;
-      }
-      kind[pr.first] = ki;
-    }
-    rowlen.push_back((int64_t)code.size() - rowstart);
-    Py_DECREF(ofast);
+  for (Py_ssize_t hi = 0; hi < nh && rc == ROW_OK; hi++) {
+    PyObject* ofast =
+        PySequence_Fast(PySequence_Fast_GET_ITEM(hfast, hi), "expected op list");
+    PyObject* keymap = kv_type ? PyDict_New() : nullptr;
+    PyObject* row_keys = kv_type ? PyList_New(0) : nullptr;
+    size_t start = L.size();
+    if (!ofast || (kv_type && (!keymap || !row_keys ||
+                               PyList_Append(labels, row_keys) < 0)))
+      rc = ROW_ERROR;
+    else
+      rc = walk_row(ofast, vocab, kinds, kv_type, keymap, row_keys, L);
+    rowlen.push_back((int64_t)(L.size() - start));
+    Py_XDECREF(ofast);
+    Py_XDECREF(keymap);
+    Py_XDECREF(row_keys);
   }
   Py_DECREF(hfast);
-
-  return Py_BuildValue(
-      "(y#y#y#y#y#y#y#)",
-      (const char*)code.data(), (Py_ssize_t)(code.size() * sizeof(int8_t)),
-      (const char*)proc.data(), (Py_ssize_t)(proc.size() * sizeof(int32_t)),
-      (const char*)kind.data(), (Py_ssize_t)(kind.size() * sizeof(int32_t)),
-      (const char*)oidx.data(), (Py_ssize_t)(oidx.size() * sizeof(int32_t)),
-      (const char*)okflag.data(), (Py_ssize_t)(okflag.size() * sizeof(int8_t)),
-      (const char*)link.data(), (Py_ssize_t)(link.size() * sizeof(int32_t)),
-      (const char*)rowlen.data(),
-      (Py_ssize_t)(rowlen.size() * sizeof(int64_t)));
+  if (rc != ROW_OK) {
+    Py_XDECREF(labels);
+    if (rc == ROW_BAIL) Py_RETURN_NONE;
+    return nullptr;
+  }
+  if (kv_type)
+    return Py_BuildValue("(y#y#y#y#y#y#y#y#N)", BUF(L.code), BUF(L.proc),
+                         BUF(L.kind), BUF(L.oidx), BUF(L.okflag),
+                         BUF(L.link), BUF(rowlen), BUF(L.key), labels);
+  return Py_BuildValue("(y#y#y#y#y#y#y#)", BUF(L.code), BUF(L.proc),
+                       BUF(L.kind), BUF(L.oidx), BUF(L.okflag), BUF(L.link),
+                       BUF(rowlen));
 }
 
 // ------------------------------------------------------------ jsonl walk
@@ -662,7 +806,7 @@ bail:
 
 PyMethodDef methods[] = {
     {"walk", walk, METH_VARARGS,
-     "walk(histories, vocab, kinds) -> flat line buffers"},
+     "walk(histories, vocab, kinds[, kv_type]) -> flat line buffers"},
     {"walk_jsonl", walk_jsonl, METH_VARARGS,
      "walk_jsonl(texts, vocab, kinds, parse) -> flat line buffers | None"},
     {nullptr, nullptr, 0, nullptr},

@@ -1715,28 +1715,55 @@ def check_batch_columnar(model: Model, histories: Sequence[List[Op]], *,
     to the per-history path (``check_batch_tpu``) when the shared
     vocabulary's state space explodes. Per-history result dicts;
     ``details="invalid"`` skips the valid rows' Python decode (see
-    check_columnar). KV-valued histories pre-partition into per-key
-    sub-histories before conversion (``partition`` — see
-    check_batch_tpu; KV values never reach the kind vocabulary)."""
+    check_columnar).
+
+    KV-valued histories (``partition``, as check_batch_tpu's) are
+    checked per key: one keyed conversion walk emits each line's key,
+    ``partition.strain_keyed`` strains the batch into per-(history,
+    key) sub-rows, and verdicts recombine per history. A batch the
+    keyed walk declines (see ops_to_columnar) or whose vocabulary
+    explodes strains as Op lists instead (``partition_histories``);
+    ``partition.oplist{route=columnar|ops}`` counts the histories each
+    way took. Both give the same subs in the same order, so results
+    and journal rows are the same either way."""
     from ..history.columnar import ops_to_columnar
     from .statespace import StateSpaceExplosion
 
     if not histories:
         return []
+    assert details in (True, "invalid"), details   # contract: List[dict]
+    kw = dict(max_slots=max_slots, host_fallback=host_fallback,
+              details=details, min_device_batch=min_device_batch,
+              scheduler=scheduler, faults=faults, journal=journal,
+              scheduler_opts=scheduler_opts)
     if partition:
-        from .partition import partition_histories, recombine_details
-        parts = partition_histories(histories,
-                                    force=partition is True)
-        if parts is not None:
-            subs, sub_hist, sub_key = parts
-            inner = check_batch_columnar(
-                model, subs, max_slots=max_slots, max_states=max_states,
-                host_fallback=host_fallback, details=details,
-                min_device_batch=min_device_batch, scheduler=scheduler,
-                faults=faults, journal=journal,
-                scheduler_opts=scheduler_opts, partition=False)
-            return recombine_details(inner, sub_hist, sub_key,
-                                     len(histories))
+        from .partition import (history_has_kv, partition_histories,
+                                recombine_details, strain_keyed)
+        force = partition is True
+        if force or any(history_has_kv(h) for h in histories):
+            try:
+                kc = ops_to_columnar(model, histories, keyed=True,
+                                     max_states=min(max_states,
+                                                    MAX_PACKED_STATES))
+            except StateSpaceExplosion:
+                kc = None
+            if kc is not None and any(kc.key_labels):
+                pb = strain_keyed(kc)
+                telemetry.REGISTRY.counter(
+                    "partition.oplist", route="columnar").inc(len(histories))
+                inner = check_columnar(model, pb.cols, partition=False, **kw)
+                return recombine_details(inner, pb.sub_history, pb.sub_key,
+                                         len(histories))
+            parts = partition_histories(histories, force=force)
+            if parts is not None:
+                subs, sub_hist, sub_key = parts
+                telemetry.REGISTRY.counter(
+                    "partition.oplist", route="ops").inc(len(histories))
+                inner = check_batch_columnar(
+                    model, subs, max_states=max_states, partition=False,
+                    **kw)
+                return recombine_details(inner, sub_hist, sub_key,
+                                         len(histories))
     try:
         cols = ops_to_columnar(model, histories,
                                max_states=min(max_states,
@@ -1749,12 +1776,7 @@ def check_batch_columnar(model: Model, histories: Sequence[List[Op]], *,
                                scheduler=scheduler, faults=faults,
                                journal=journal,
                                scheduler_opts=scheduler_opts)
-    assert details in (True, "invalid"), details   # contract: List[dict]
-    return check_columnar(model, cols, max_slots=max_slots, details=details,
-                          host_fallback=host_fallback,
-                          min_device_batch=min_device_batch,
-                          scheduler=scheduler, faults=faults,
-                          journal=journal, scheduler_opts=scheduler_opts)
+    return check_columnar(model, cols, **kw)
 
 
 def check_synth(model: Model, spec, *, synth: str = "device",

@@ -14,6 +14,10 @@ with witness provenance. This suite pins:
     space, witness key) — fault-free, under every single-fault
     FaultPlan schedule, and across kill-and-resume with ZERO decided
     sub-histories re-dispatched;
+  * the keyed Op-list route (one keyed conversion walk, then the
+    columnar strain): native walk == Python twin, strained sub-batch ==
+    the Op-list strain's field for field, dict-equal results, every
+    bail condition back on the Op-list strain, and journal resume;
   * the fused dispatch budget (the tier-1 guard against regressing to
     per-chunk dispatch) and the cost model's measured per-dispatch
     overhead term.
@@ -21,23 +25,34 @@ with witness provenance. This suite pins:
 Deterministic, test-scale, hermetic (conftest pins JT_COMPILE_CACHE=0
 and JT_DISPATCH_OVERHEAD_US=0) — this suite is tier-1.
 """
+import json
+from pathlib import Path
+
 import numpy as np
 import pytest
 
+from benchmark import generator
+from jepsen_tpu import telemetry
 from jepsen_tpu.checkers.linearizable import wgl_check
-from jepsen_tpu.history.columnar import PAD, columnar_to_ops
-from jepsen_tpu.history.ops import invoke_op, ok_op
+from jepsen_tpu.history.columnar import (PAD, _from_bufs, _seed_vocab,
+                                         _walk_py, columnar_to_ops,
+                                         ops_to_columnar)
+from jepsen_tpu.history.core import index as index_history
+from jepsen_tpu.history.ops import (Op, fail_op, info_op, invoke_op,
+                                    ok_op)
 from jepsen_tpu.independent import KV, is_kv, subhistory
 from jepsen_tpu.models.core import cas_register
 from jepsen_tpu.ops.faults import (FaultInjector, FaultPlan,
                                    InjectedKill, single_fault_schedules)
-from jepsen_tpu.ops.linearize import (DISPATCH_LOG, check_batch_tpu,
-                                      check_columnar)
+from jepsen_tpu.native import ingest
+from jepsen_tpu.ops.linearize import (DISPATCH_LOG, check_batch_columnar,
+                                      check_batch_tpu, check_columnar)
 from jepsen_tpu.ops.partition import (merge_kv_histories,
                                       partition_columnar,
                                       partition_histories,
                                       pending_w_hist,
-                                      recombine_verdicts)
+                                      recombine_details,
+                                      recombine_verdicts, strain_keyed)
 from jepsen_tpu.ops.schedule import (BucketScheduler, choose_w_classes,
                                      measure_dispatch_overhead_us)
 from jepsen_tpu.store import ChunkJournal
@@ -260,6 +275,198 @@ def test_kill_and_resume_redispatches_zero_decided_subhistories(
     redispatched = sum(n for _, _, _, n in DISPATCH_LOG)
     assert redispatched <= n_subs - len(decided), \
         "decided sub-histories must not be re-dispatched"
+    j2.finish()
+
+
+# ---------------------------------------- keyed Op-list route
+
+CONFIGS = Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+SEED = 3900000092
+
+
+def _pool(name, n, **scale):
+    """``n`` seeded histories of a benchmark configuration (``scale``
+    overrides its sizes) and the model its cells check them under."""
+    cfg = dict(json.loads((CONFIGS / f"{name}.json").read_text()), **scale)
+    pool = generator.generate(cfg, n, SEED, max(1, n // 4))
+    return ([pool.op_list(i) for i in range(pool.n)],
+            cas_register(cfg["initial"]))
+
+
+def _mixed():
+    """KV histories with every op the strain must place: nemesis ops,
+    :fail and :info completions, crashed invocations, an :info that
+    drops the key, an unkeyed client op pair, keys first seen out of
+    label order with two of them invalid; then a history without keys
+    and an empty one."""
+    parts = {k: synth_cas_history(200 + i, n_procs=3, n_ops=12,
+                                  p_info=0.2, corrupt=float(i > 0))
+             for i, k in enumerate(("a", "b", 7))}
+    h = merge_kv_histories(parts)
+    h = ([Op("nemesis", "info", "start", None),
+          invoke_op(51, "cas", KV(7, [1, 2]))]            # crashed
+         + h[:9] + [invoke_op(60, "read", None), ok_op(60, "read", None)]
+         + h[9:20] + [invoke_op(50, "write", KV("b", 3)),
+                      fail_op(50, "write", KV("b", 3)),
+                      invoke_op(52, "write", KV("a", 4)),
+                      info_op(52, "write", None)]
+         + h[20:] + [Op("nemesis", "info", "stop", None)])
+    for op in h:
+        op.index = None
+    return [index_history(h),
+            index_history(synth_cas_history(300, n_procs=2, n_ops=6)),
+            []], cas_register()
+
+
+CASES = {
+    "etcd": lambda: _pool("etcd-register", 4),
+    "cockroach": lambda: _pool("cockroach-register", 2),
+    "mixed": _mixed,
+}
+
+
+def _kinds(cols):
+    """Each line's op kind itself (None off invoke lines): kinds up to
+    the vocabulary's numbering."""
+    return [[cols.kinds[k] if k >= 0 else None for k in row]
+            for row in cols.kind.tolist()]
+
+
+def _oplist_route(model, histories, **kw):
+    """Today's Op-list strain, spelled out: partition_histories, one
+    unkeyed check of the subs, recombination."""
+    subs, sub_hist, sub_key = partition_histories(histories)
+    inner = check_batch_columnar(model, subs, partition=False, **kw)
+    return recombine_details(inner, sub_hist, sub_key, len(histories))
+
+
+def _routes():
+    c = telemetry.REGISTRY.counters()
+    return {r: c.get(f"partition.oplist{{route={r}}}", 0)
+            for r in ("columnar", "ops")}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_keyed_walk_native_matches_python(case):
+    hists, _ = CASES[case]()
+    ext = ingest()
+    assert ext is not None
+    vocab_n, kinds_n = _seed_vocab(None)
+    vocab_p, kinds_p = _seed_vocab(None)
+    native = _from_bufs(ext.walk(hists, vocab_n, kinds_n, KV))
+    python = _walk_py(hists, vocab_p, kinds_p, KV)
+    for n, p in zip(native[:8], python[:8]):
+        np.testing.assert_array_equal(n, p)
+    assert native[8] == python[8] and kinds_n == kinds_p
+
+
+@pytest.mark.parametrize("native", [True, False])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_keyed_strain_matches_oplist_strain(case, native):
+    """The keyed walk + columnar strain gives the Op-list strain's subs,
+    in its order, field for field."""
+    hists, model = CASES[case]()
+    subs, sub_hist, sub_key = partition_histories(hists)
+    want = ops_to_columnar(model, subs)
+    pb = strain_keyed(ops_to_columnar(model, hists, keyed=True,
+                                      native=native))
+    assert pb.sub_history.tolist() == sub_hist.tolist()
+    assert pb.sub_key == sub_key
+    for f in ("type", "process", "index"):
+        np.testing.assert_array_equal(getattr(pb.cols, f),
+                                      getattr(want, f), err_msg=f)
+    assert _kinds(pb.cols) == _kinds(want)
+
+
+@pytest.mark.parametrize("details", [True, "invalid"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_keyed_route_results_match_oplist_route(case, details):
+    """Dict for dict — verdict, first bad op, witness key, failures in
+    first-seen key order, provenance — the keyed route answers as the
+    Op-list strain does, on valid and altered histories."""
+    hists, model = (_pool("etcd-register", 4, ops_per_key=40)
+                    if case == "etcd" else
+                    _pool("cockroach-register", 2, ops_per_key=20)
+                    if case == "cockroach" else CASES[case]())
+    before = _routes()
+    got = check_batch_columnar(model, hists, details=details)
+    after = _routes()
+    assert after["columnar"] - before["columnar"] == len(hists)
+    assert after["ops"] == before["ops"]
+    assert got == _oplist_route(model, hists, details=details)
+    assert any(r["valid"] is True for r in got)
+    assert any(r["valid"] is False for r in got)
+    if case == "mixed":
+        assert got[0]["failures"] == [7, "b"]
+
+
+def _bail_history(case):
+    p = 3
+    if case == "op-without-index":
+        return [invoke_op(p, "write", KV("a", 1)),
+                ok_op(p, "write", KV("a", 1))]
+    h = {"completion-names-another-key":
+         [invoke_op(p, "write", KV("a", 1)), ok_op(p, "write", KV("b", 1))],
+         "fail-names-another-key":
+         [invoke_op(p, "write", KV("a", 1)),
+          fail_op(p, "write", KV("b", 1))],
+         "unkeyed-invocation-keyed-completion":
+         [invoke_op(p, "read", None), ok_op(p, "read", KV("a", None))],
+         "invocation-under-another-open-key":
+         [invoke_op(p, "write", KV("a", 1)),
+          invoke_op(p, "write", KV("b", 2)),
+          ok_op(p, "write", KV("b", 2))]}[case]
+    return index_history([invoke_op(0, "write", KV("a", 2)),
+                          ok_op(0, "write", KV("a", 2))] + h)
+
+
+@pytest.mark.parametrize("case", [
+    "completion-names-another-key", "fail-names-another-key",
+    "unkeyed-invocation-keyed-completion",
+    "invocation-under-another-open-key", "op-without-index"])
+def test_keyed_walk_bails_to_the_oplist_strain(case):
+    hists = [_bail_history(case)] + _pool("cockroach-register", 1,
+                                          ops_per_key=10)[0]
+    model = cas_register()
+    for native in (True, False):
+        assert ops_to_columnar(model, hists, keyed=True,
+                               native=native) is None
+    before = _routes()
+    got = check_batch_columnar(model, hists)
+    after = _routes()
+    assert after["ops"] - before["ops"] == len(hists)
+    assert after["columnar"] == before["columnar"]
+    assert got == _oplist_route(model, hists, details=True)
+
+
+def test_keyed_route_resume_redispatches_zero_decided_subhistories(
+        tmp_path):
+    """The journal contract on the keyed Op-list route: an interrupted
+    check resumes with zero decided sub-histories re-dispatched and the
+    same verdicts."""
+    hists, model = _pool("cockroach-register", 4, ops_per_key=20)
+    opts = {"chunk_rows": 16, "shard_min_rows": 1 << 30}
+    want = check_batch_columnar(model, hists, scheduler_opts=opts)
+    key = {"digest": "keyed-oplist-kill"}
+    j1 = ChunkJournal(tmp_path / "k.jsonl", key)
+    inj = FaultInjector(FaultPlan.single("dispatch", "kill", chunk=3,
+                                         deadline_s=5.0))
+    with pytest.raises(InjectedKill):
+        check_batch_columnar(model, hists, faults=inj, journal=j1,
+                             scheduler_opts=opts)
+    j1.close()
+    j2 = ChunkJournal(tmp_path / "k.jsonl", key, resume=True)
+    decided = j2.decided()
+    n_subs = len(partition_histories(hists)[0])
+    assert decided and len(decided) < n_subs
+    DISPATCH_LOG.clear()
+    got = check_batch_columnar(model, hists, journal=j2,
+                               scheduler_opts=opts)
+    assert [(r["valid"], (r.get("op") or {}).get("index"))
+            for r in got] == [(r["valid"], (r.get("op") or {}).get("index"))
+                              for r in want]
+    assert j2.resume_hits == len(decided)
+    assert sum(n for _, _, _, n in DISPATCH_LOG) <= n_subs - len(decided)
     j2.finish()
 
 
