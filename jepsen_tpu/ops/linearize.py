@@ -509,7 +509,39 @@ def _round_up_to(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def run_encoded_batch(batch: EncodedBatch, return_frontier: bool = False):
+def dispatch_route(batch: EncodedBatch):
+    """(route, mesh) of one bucket on the attached devices, as
+    ``run_encoded_batch`` runs it: "frontier" (mask axis split over
+    2^(W - DATA_MAX_SLOTS) devices), "data1wide" (a window past
+    DATA_MAX_SLOTS on one device, HBM-resident, when the devices can't
+    host the frontier axis), "dataN" (batch axis sharded) or "data1"
+    (one device). Raises WindowOverflow when no device route can host
+    the window — callers route those rows to a host engine."""
+    if batch.W > DATA_MAX_SLOTS:
+        D = 1 << (batch.W - DATA_MAX_SLOTS)
+        mesh = production_mesh(D)
+        if mesh is not None:
+            return "frontier", mesh
+        if batch.W - DATA_MAX_SLOTS <= SINGLE_DEVICE_EXTRA_SLOTS:
+            # Not enough devices to shard the mask axis: run the wide
+            # window on one device, HBM-resident, with the batch chunk
+            # shrunk in proportion (time for memory — the single-chip
+            # degradation path).
+            return "data1wide", None
+        raise WindowOverflow(
+            f"window W={batch.W} needs {D} frontier devices")
+    mesh = production_mesh(1)
+    from ..parallel.mesh import should_shard
+    if should_shard(batch.batch, mesh):
+        return "dataN", mesh
+    # Sub-minimum sharding (rows/device below the $JT_SHARD_MIN_ROWS
+    # floor) regresses — MULTICHIP_r06's 4/8-device points — so thin
+    # batches stay on one device.
+    return "data1", None
+
+
+def run_encoded_batch(batch: EncodedBatch, return_frontier: bool = False,
+                      route=None):
     """Device-check one cost bucket; routes to the right kernel for the
     bucket's window and the attached devices:
 
@@ -530,7 +562,8 @@ def run_encoded_batch(batch: EncodedBatch, return_frontier: bool = False):
     and with ``return_frontier="invalid"`` a dict {row: frontier_row}
     holding ONLY the invalid rows — gathered on device, so the replay
     product path never ships the valid majority's frontiers across a
-    latency-bound link.
+    latency-bound link. ``route``: the bucket's ``dispatch_route``,
+    when the caller has it already.
     """
     if batch.batch == 0:
         z = np.zeros((0,), bool)
@@ -539,33 +572,11 @@ def run_encoded_batch(batch: EncodedBatch, return_frontier: bool = False):
                        if return_frontier else None)
         return z, np.zeros((0,), np.int32), empty_front
 
-    if batch.W > DATA_MAX_SLOTS:
-        D = 1 << (batch.W - DATA_MAX_SLOTS)
-        mesh = production_mesh(D)
-        if mesh is not None:
-            pending = _dispatch_sharded("frontier", batch, mesh,
-                                        return_frontier)
-        elif batch.W - DATA_MAX_SLOTS <= SINGLE_DEVICE_EXTRA_SLOTS:
-            # Not enough devices to shard the mask axis: run the wide
-            # window on one device, HBM-resident, with the batch chunk
-            # shrunk in proportion (time for memory — the single-chip
-            # degradation path).
-            pending = _data1_dispatch(batch, return_frontier,
-                                      label="data1wide")
-        else:
-            raise WindowOverflow(
-                f"window W={batch.W} needs {D} frontier devices")
+    route, mesh = route or dispatch_route(batch)
+    if route in ("frontier", "dataN"):
+        pending = _dispatch_sharded(route, batch, mesh, return_frontier)
     else:
-        mesh = production_mesh(1)
-        from ..parallel.mesh import should_shard
-        if should_shard(batch.batch, mesh):
-            pending = _dispatch_sharded("dataN", batch, mesh,
-                                        return_frontier)
-        else:
-            # Sub-minimum sharding (rows/device below the
-            # $JT_SHARD_MIN_ROWS floor) regresses — MULTICHIP_r06's
-            # 4/8-device points — so thin batches stay on one device.
-            pending = _data1_dispatch(batch, return_frontier)
+        pending = _data1_dispatch(batch, return_frontier, label=route)
 
     valids, bads, fronts = [], [], []
     front_map = {} if return_frontier == "invalid" else None
@@ -1636,26 +1647,38 @@ def _check_columnar_impl(model: Model, cols, *, max_slots, host_fallback,
     return valid, bad
 
 
-_SUBS_COUNTERS: Dict[str, object] = {}
+_SUBS_COUNTERS: Dict[Tuple[str, str], object] = {}
 
 
 def _count_subs(n_rows: int, sch, host_rows: set) -> None:
     """``check.subs{provenance=...}``: the rows one check finalized under
-    each provenance (``device`` when no other applies), one increment
-    per value."""
+    each provenance (``device`` when no other applies); and, when the
+    scheduler ran, ``check.routes{route=...}``: the same rows by the
+    route that decided them (the scheduler's ``row_route``; ``data1``,
+    its one-device chunk pipeline, when no other applies; ``host`` for
+    the host engines). One increment per value."""
     counts = {"host-fallback": len(host_rows)}
+    tallies = [("check.subs", "provenance", counts)]
     if sch is not None:
+        routes = {"host": len(host_rows)}
         for row, prov in sch.row_provenance.items():
             if row not in host_rows:
                 counts[prov] = counts.get(prov, 0) + 1
+        for row, route in sch.row_route.items():
+            if row not in host_rows:
+                routes[route] = routes.get(route, 0) + 1
+        routes["data1"] = (routes.get("data1", 0) + n_rows
+                           - sum(routes.values()))
+        tallies.append(("check.routes", "route", routes))
     counts["device"] = n_rows - sum(counts.values())
-    for prov, n in counts.items():
-        if n:
-            c = _SUBS_COUNTERS.get(prov)
-            if c is None:
-                c = _SUBS_COUNTERS[prov] = telemetry.REGISTRY.counter(
-                    "check.subs", provenance=prov)
-            c.inc(n)
+    for name, label, tally in tallies:
+        for value, n in tally.items():
+            if n:
+                c = _SUBS_COUNTERS.get((name, value))
+                if c is None:
+                    c = _SUBS_COUNTERS[(name, value)] = \
+                        telemetry.REGISTRY.counter(name, **{label: value})
+                c.inc(n)
 
 
 def _sink_verdict(sink, row: int, r: dict) -> None:

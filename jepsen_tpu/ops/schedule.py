@@ -18,9 +18,10 @@ layer:
     wider class is semantics-preserving (ops.encode.widen_batch: the
     extra slots stay empty in every snapshot, contribute all-zero
     packed target rows, and can never acquire mask bits — the config
-    set is bit-identical, embedded in a wider mask axis). Windows past
-    DATA_MAX_SLOTS keep exact classes: their mask axis is
-    shape-critical to the wide/frontier dispatch routes.
+    set is bit-identical, embedded in a wider mask axis). Classes past
+    DATA_MAX_SLOTS are priced as their route runs them
+    (frontier_class_cost): per event step on a frontier mesh, where
+    narrower rows may ride them.
 
   * **persistent compilation cache + pre-warm** — the scheduler wires
     jax's persistent compilation cache (enable_compilation_cache) so
@@ -69,7 +70,9 @@ quarantines poison rows to the caller's host engine — so a single bad
 chunk degrades instead of aborting a multi-thousand-history check.
 Quarantined rows surface in ``quarantined`` (callers MUST re-decide
 them host-side; the in-band verdict is an inert placeholder) and every
-off-happy-path row is tagged in ``row_provenance``.
+off-happy-path row is tagged in ``row_provenance``; every row a
+wide/frontier/sharded dispatch decided is tagged with its route
+(linearize.dispatch_route) in ``row_route``.
 """
 from __future__ import annotations
 
@@ -89,7 +92,7 @@ from .faults import (CorruptOutput, FaultInjector, WatchdogExpired,
                      classify_failure, corrupt_arrays, validate_decoded)
 from .linearize import (DATA_MAX_SLOTS, DISPATCH_LOG, INT32_MAX,
                         KERNEL_SHAPE_LOG, MAX_FRONTIER_ELEMENTS,
-                        WindowOverflow,
+                        WindowOverflow, dispatch_route,
                         get_fused_kernel, get_kernel, log_kernel_shapes,
                         n_state_words, production_mesh, run_encoded_batch,
                         run_event_chunked, vpu_op_model)
@@ -362,22 +365,50 @@ def dispatch_overhead_units() -> float:
             * DISPATCH_COST_LANE_OPS_PER_S)
 
 
+def frontier_class_cost(events: int, rows: int, W: int, devices: int,
+                        boundary: int = DATA_MAX_SLOTS) -> float:
+    """Cost-base units of ``rows`` rows of ``events`` event steps (the
+    merged bucket's padded event axis) checked at a class ``W`` past
+    ``boundary`` on ``devices`` devices, as the routes run it
+    (linearize.dispatch_route). With the 2^(W - boundary) devices the
+    frontier axis needs, every event step scans 2^boundary masks per
+    device for the rows of its data shard, and a step over fewer than
+    ROW_QUANTUM rows costs what a quantum's does: its latency, the
+    hypercube exchanges and the convergence psums set it, not its rows.
+    So the step is priced per device, as a narrow class's is on the one
+    device it leaves the others idle beside. Without those devices the
+    class runs on one device (data1wide), priced rows x events x 2^W as
+    the narrow classes are."""
+    D = 1 << (W - boundary)
+    if D > devices:
+        return float(rows * events) * float(1 << W)
+    per_shard = max(ROW_QUANTUM, -(-rows // (devices // D)))
+    return float(events * per_shard) * float(1 << boundary)
+
+
 def choose_w_classes(stats: Dict[Tuple[int, int], float], *,
                      max_classes: int = DEFAULT_MAX_CLASSES,
                      boundary: int = DATA_MAX_SLOTS,
-                     overhead: Optional[float] = None
+                     overhead: Optional[float] = None,
+                     rows: Optional[Dict[Tuple[int, int], int]] = None,
+                     devices: int = 1
                      ) -> Dict[Tuple[int, int], int]:
     """Pick the W classes: {(V, exact_W): class_W}.
 
     ``stats`` maps (V, exact_W) -> cost base (rows x events; anything
-    proportional works). Per V, the exact windows <= ``boundary``
-    partition into at most ``max_classes`` contiguous groups, each
-    checked at its widest member; the dynamic program minimizes
-    sum(base_group x 2^class_W + overhead) — total padded frontier
-    work plus a per-group dispatch tax — over all such partitions.
-    Windows past the boundary keep exact classes: they dispatch
-    through the wide/frontier routes, where the mask axis is
-    shape-critical (and they are rare).
+    proportional works, but past the boundary ``stats / rows`` is read
+    as the bucket's event steps). Per V, the exact windows partition
+    into at most ``max_classes`` contiguous groups, each checked at its
+    widest member; the dynamic program minimizes the sum of the groups'
+    costs plus a per-group dispatch tax. A group checked at or below
+    ``boundary`` costs base_group x 2^class_W, its total padded
+    frontier work. A group checked past the boundary costs what
+    frontier_class_cost prices for its rows on ``devices`` devices
+    (``rows``: the row count of each (V, exact_W); without it, the
+    narrow formula), so on a frontier mesh narrower rows ride a wide
+    class (ops.encode.widen_batch is exact) where that costs less than
+    a dispatch of their own. A V with no window past the boundary gets
+    the same classes whatever ``rows`` and ``devices`` say.
 
     ``overhead`` is the measured fixed cost of one dispatch in
     cost-base units (default dispatch_overhead_units(), i.e. the
@@ -392,22 +423,24 @@ def choose_w_classes(stats: Dict[Tuple[int, int], float], *,
     out: Dict[Tuple[int, int], int] = {}
     by_v: Dict[int, List[int]] = {}
     for (v, w) in stats:
-        if w <= boundary:
-            by_v.setdefault(v, []).append(w)
-        else:
-            out[(v, w)] = w
+        by_v.setdefault(v, []).append(w)
     for v, ws in by_v.items():
         ws = sorted(set(ws))
-        if len(ws) <= max_classes and not overhead:
+        if len(ws) <= max_classes and not overhead and ws[-1] <= boundary:
             out.update({(v, w): w for w in ws})
             continue
         base = [float(stats[(v, w)]) for w in ws]
+        nrow = [int(rows[(v, w)]) for w in ws] if rows is not None else None
         pre = [0.0]
         for b in base:
             pre.append(pre[-1] + b)
 
         def cost(i, j):        # group ws[i..j] checked at ws[j]
-            return (pre[j + 1] - pre[i]) * float(1 << ws[j]) + overhead
+            if ws[j] <= boundary or nrow is None:
+                return (pre[j + 1] - pre[i]) * float(1 << ws[j]) + overhead
+            events = max(base[k] / nrow[k] for k in range(i, j + 1))
+            return frontier_class_cost(events, sum(nrow[i:j + 1]), ws[j],
+                                       devices, boundary) + overhead
 
         n = len(ws)
         INF = float("inf")
@@ -1322,10 +1355,13 @@ class BucketScheduler:
         # their host engine — the in-band verdict is an inert
         # placeholder), provenance tags for every off-happy-path row
         # ("device-retried" / "host-fallback"; untagged rows are plain
-        # "device"), and the learned safe rows-per-dispatch per
+        # "device"), the route of every row a wide/frontier/sharded
+        # dispatch decided (untagged rows ran the one-device chunk
+        # pipeline), and the learned safe rows-per-dispatch per
         # (V, W class) after an OOM bisection.
         self.quarantined: Dict[int, str] = {}
         self.row_provenance: Dict[int, str] = {}
+        self.row_route: Dict[int, str] = {}
         self._safe_bp: Dict[Tuple[int, int], int] = {}
         self._awaited_shapes: set = set()
         # Encode→dispatch hand-off bound (JT_SCHED_MAX_QUEUE): chunks
@@ -1556,7 +1592,8 @@ class BucketScheduler:
             # mode — falls through to the unchanged scan below.
             from .dc_monitor import dc_prefilter_chunk
             with telemetry.span("dispatch", cat="device",
-                                family="wgl-dc", V=batch.V, W=batch.W,
+                                family="wgl-dc", route="data1",
+                                V=batch.V, W=batch.W,
                                 rows=hi - lo, chunk=ordinal, tag=tag):
                 decided = dc_prefilter_chunk(batch, lo, hi)
             if decided is not None:
@@ -1577,8 +1614,8 @@ class BucketScheduler:
         use_pallas = self._pallas_for(batch)
         family = "wgl-pallas" if use_pallas else "wgl"
         with telemetry.span("dispatch", cat="device", family=family,
-                            V=batch.V, W=batch.W, rows=hi - lo,
-                            chunk=ordinal, tag=tag):
+                            route="data1", V=batch.V, W=batch.W,
+                            rows=hi - lo, chunk=ordinal, tag=tag):
             if use_pallas:
                 kern = self._resolve_pallas(batch, Bp, Np)
                 log_kernel_shapes(batch.V, batch.W, "pallas",
@@ -1721,7 +1758,7 @@ class BucketScheduler:
                     prewarm_kernels([gspec])
                 with telemetry.span(
                         "dispatch", cat="device", family="wgl",
-                        fused=True,
+                        route="data1", fused=True,
                         fuse_group=group_id, members=len(fused_pos),
                         rows=sum(members[p][2] - members[p][1]
                                  for p in fused_pos),
@@ -2155,12 +2192,15 @@ class BucketScheduler:
                 # One XLA call per attempt — the wide/frontier routes
                 # count toward dispatch economics like any other ship.
                 self._inc("dispatches")
+                route = dispatch_route(mb)
                 with telemetry.span("dispatch", cat="device",
-                                    family="wgl", route="wide",
+                                    family="wgl", route=route[0],
                                     V=mb.V, W=mb.W, rows=mb.batch):
-                    out = run_encoded_batch(mb, self.return_frontier)
-                if attempt:
-                    for i in mb.indices:
+                    out = run_encoded_batch(mb, self.return_frontier,
+                                            route=route)
+                for i in mb.indices:
+                    self.row_route[i] = route[0]
+                    if attempt:
                         self.row_provenance.setdefault(i, "device-retried")
                 return out
             except WindowOverflow as e:
@@ -2185,13 +2225,25 @@ class BucketScheduler:
         if not self.consolidate:
             return {(b.V, b.W): b.W for b in group}
         stats: Dict[Tuple[int, int], float] = {}
+        rows: Dict[Tuple[int, int], int] = {}
         for b in group:
             if b.batch:
                 stats[(b.V, b.W)] = (stats.get((b.V, b.W), 0.0)
                                      + b.batch * b.n_events)
+                rows[(b.V, b.W)] = rows.get((b.V, b.W), 0) + b.batch
         self.stats["dispatch_overhead_us"] = round(
             measure_dispatch_overhead_us(), 2)
-        return choose_w_classes(stats, max_classes=self.max_classes)
+        import jax
+        plan = dict(rows=rows, devices=len(jax.devices()))
+        if self.wgl_backend in ("dc", "pallas"):
+            # A pinned narrow-window backend keeps its rows: the wide
+            # windows are planned apart, so no narrow row rides them.
+            wide = {k: b for k, b in stats.items() if k[1] > DATA_MAX_SLOTS}
+            narrow = {k: b for k, b in stats.items() if k not in wide}
+            return {**choose_w_classes(narrow, max_classes=self.max_classes),
+                    **choose_w_classes(wide, max_classes=self.max_classes,
+                                       **plan)}
+        return choose_w_classes(stats, max_classes=self.max_classes, **plan)
 
     def _class_of(self, class_map: Dict, V: int, W: int) -> int:
         cw = class_map.get((V, W))
@@ -2199,8 +2251,8 @@ class BucketScheduler:
             if not self.consolidate or W > DATA_MAX_SLOTS:
                 # Exact class: consolidate=False promises exact-W for
                 # EVERY window, including ones first seen in later
-                # groups; and wide windows always stay exact (the
-                # module contract) — on the wide/frontier route cost
+                # groups; and a wide window first seen in a later
+                # group stays exact — on the wide/frontier route cost
                 # is 2^W per row, so riding a wider compiled class
                 # would multiply the dominant frontier traffic, not
                 # save a compile.

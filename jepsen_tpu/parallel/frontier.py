@@ -195,4 +195,10 @@ def frontier_sharded_kernel(V: int, W: int, mesh: Mesh,
                         in_specs=(ev, ev, P("data", None, None), tgt),
                         out_specs=(P("data"), P("data"),
                                    P("data", None, "frontier")))
-    return jax.jit(sharded)
+
+    # A name of its own: the profiler shows this program as
+    # ``jit_frontier_check``, apart from the data-sharded kernel.
+    def frontier_check(ev_type, ev_slot, ev_slots, target):
+        return sharded(ev_type, ev_slot, ev_slots, target)
+
+    return jax.jit(frontier_check)

@@ -74,6 +74,32 @@ def test_wgl_scan_compiles(sd, V, W, B):
              _wgl_args(sd, B, 1024, W, V))
 
 
+@pytest.mark.parametrize("W,n_frontier", [(17, 2), (18, 4)])
+def test_frontier_sharded_compiles_on_four_chips(topo, W, n_frontier):
+    """The frontier-sharded kernel on the described 2x2 host, at the
+    four-chip cell's shape: 64 rows, 2^W masks split over
+    ``n_frontier`` chips, hypercube exchanges and psums."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jepsen_tpu.parallel.frontier import frontier_sharded_kernel
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(4 // n_frontier,
+                                                   n_frontier),
+                ("data", "frontier"))
+
+    def arg(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, np.dtype(dtype),
+                                    sharding=NamedSharding(mesh, P(*spec)))
+    B, N, V, K1 = 64, 256, 8, 32
+    compiled = _compile(
+        frontier_sharded_kernel(V, W, mesh),
+        (arg((B, N), np.int8, "data"), arg((B, N), np.int8, "data"),
+         arg((B, N, W), np.int8, "data"),
+         arg((B, K1, V), np.int32, "data")))
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_frontier_check")
+    assert "collective-permute" in text and "all-reduce" in text
+
+
 @pytest.mark.parametrize("V,W,shared", [
     (8, 8, True), (64, 10, True), (64, 10, False), (8, 1, True)])
 def test_pallas_wgl_compiles(sd, V, W, shared):
